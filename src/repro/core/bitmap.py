@@ -122,6 +122,21 @@ class BitmapMatrix:
         return jnp.sum(self.counts)
 
 
+def nonzero(x: jax.Array) -> jax.Array:
+    """Exact non-zero mask, subnormals included.
+
+    XLA flushes subnormal floats to zero in float compares on CPU and
+    TPU, so ``x != 0`` drops them while numpy keeps them.  Testing the
+    bit pattern without the sign bit keeps every non-zero value, which
+    is what the bitmap promises.
+    """
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return x != 0
+    bits = jax.lax.bitcast_convert_type(
+        x, jnp.dtype(f"uint{jnp.dtype(x.dtype).itemsize * 8}"))
+    return (bits << 1) != 0
+
+
 def _condense(x: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
     """Stable-push the masked elements of ``x`` to the front along ``axis``.
 

@@ -106,7 +106,7 @@ def im2col_bitmap(x: jax.Array, kh: int, kw: int, stride: int
 
     # channel-first working layout: (C, H, W)
     xc = jnp.moveaxis(x, -1, 0)
-    maskc = xc != 0                                   # S0 bitmap
+    maskc = bm.nonzero(xc)                            # S0 bitmap
     # cumulative popcount per feature-map row: offset of each position's
     # value inside the row's condensed value list (S3 shifted-out bits).
     cumc = jnp.cumsum(maskc, axis=2) - maskc          # exclusive prefix

@@ -58,8 +58,14 @@ def block_mask(w: jax.Array, sparsity: float,
     k, n = w.shape
     bk, bn = block
     kt, nt = -(-k // bk), -(-n // bn)
-    padded = jnp.pad(jnp.square(w), ((0, kt * bk - k), (0, nt * bn - n)))
-    norms = jnp.sum(padded.reshape(kt, bk, nt, bn), axis=(1, 3))  # (Kt,Nt)
+    # tile norms one (bk, N) row slab at a time, and the (K, N) mask by
+    # splitting only the major axis: neither needs a (K, N)-sized f32
+    # temporary or a relayout at published widths
+    slabs = jnp.pad(w, ((0, kt * bk - k), (0, nt * bn - n))).reshape(
+        kt, bk, nt * bn)
+    norms = jax.lax.map(lambda s: jnp.sum(jnp.square(
+        s.astype(jnp.float32)).reshape(bk, nt, bn), axis=(0, 2)),
+        slabs)                                                    # (Kt,Nt)
     keep = int(round(kt * nt * (1.0 - sparsity)))
     if keep >= kt * nt:
         return jnp.ones_like(w, dtype=bool)
@@ -67,8 +73,9 @@ def block_mask(w: jax.Array, sparsity: float,
     # constant/quantized weights — must still keep exactly `keep` tiles
     rank = jnp.argsort(jnp.argsort(norms.reshape(-1)))
     tile_keep = (rank >= kt * nt - keep).reshape(kt, nt)          # (Kt,Nt)
-    full = jnp.repeat(jnp.repeat(tile_keep, bk, axis=0), bn, axis=1)
-    return full[:k, :n]
+    cols = jnp.repeat(tile_keep, bn, axis=1)                      # (Kt,N)
+    full = jnp.broadcast_to(cols[:, None, :], (kt, bk, nt * bn))
+    return full.reshape(kt * bk, nt * bn)[:k, :n]
 
 
 def structured_24_mask(w: jax.Array, axis: int = -1) -> jax.Array:

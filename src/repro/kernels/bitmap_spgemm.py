@@ -33,13 +33,123 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import platform
+
 SLICE_K = 128  # MXU-native contraction depth = unit of sparsity skip
 
 
+# Scalar-prefetched schedule words per pallas_call.  The schedule lives in
+# SMEM (1 MiB on v5e); a call whose schedule would not fit is split into
+# calls over sub-rectangles of the output-block grid (each call re-reads
+# only the operand blocks its own output blocks need, exactly as the one
+# big grid would).  Prefetch arrays are passed flattened to 1-D: SMEM pads
+# a multi-dimensional array's minor dimension, so a (E, Mt, Nt, 2)
+# schedule took 64x its size.
+SMEM_SCHEDULE_WORDS = 96 * 1024
+
+
 def _compiler_params(dimension_semantics):
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
+    from repro.sparse import plan as pln
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=pln.VMEM_BYTES)
+
+
+def mxu_dot(a: jax.Array, b: jax.Array, interpret: bool,
+            contract=((1,), (0,))) -> jax.Array:
+    """f32-accumulating product of two operand tiles.
+
+    On the TPU the operands reach the MXU in their own dtype (bf16 stays
+    bf16) with f32 accumulation.  Interpret mode upcasts them to f32
+    first: the CPU backend has no bf16 x bf16 -> f32 dot.  Both forms
+    are exact products summed in f32 — bf16 values are exact in f32 —
+    so only the summation order can differ.
+    """
+    if interpret:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def grid_split(n_outer: int, n_inner: int, words_per_block: int
+               ) -> Tuple[int, int]:
+    """(outer, inner) block counts per call so that one call's flattened
+    schedule stays within :data:`SMEM_SCHEDULE_WORDS`."""
+    inner = max(1, min(n_inner, SMEM_SCHEDULE_WORDS // words_per_block))
+    outer = max(1, min(n_outer,
+                       SMEM_SCHEDULE_WORDS // (inner * words_per_block)))
+    return outer, inner
+
+
+def over_blocks(call, a, b, *schedule, block_m: int, block_n: int,
+                words_per_block: int):
+    """Run ``call(a_rows, b_cols, *schedule_blocks)`` over rectangles of
+    the (Mt, Nt) output-block grid whose schedules fit SMEM
+    (:func:`grid_split`) and stitch the output blocks back together."""
+    mt, nt = schedule[0].shape[:2]
+    rows, cols = grid_split(mt, nt, words_per_block)
+    out = []
+    for i0 in range(0, mt, rows):
+        i1 = min(i0 + rows, mt)
+        out.append(jnp.concatenate([
+            call(a[i0 * block_m:i1 * block_m],
+                 b[:, j0 * block_n:min(j0 + cols, nt) * block_n],
+                 *(x[i0:i1, j0:j0 + cols] for x in schedule))
+            for j0 in range(0, nt, cols)], axis=1))
+    return jnp.concatenate(out, axis=0)
+
+
+def chunk_ranges(gk: jax.Array, slice_k: int) -> Tuple[jax.Array,
+                                                        jax.Array]:
+    """First and last ``slice_k``-wide k-chunk each condensed step reads.
+
+    gk (..., S, slice_k) packed schedule.  A step's head lanes (the
+    block's active k's) ascend, so they span chunks
+    ``gk[..., 0] // slice_k`` to the chunk of the last head lane; the
+    tail of the last step restarts at the smallest inactive k, which
+    shows as the first descent.  Lanes outside the range gather nothing
+    (zero), lanes inside it are exact — tail lanes read k's whose outer
+    product is zero either way.  Returns (qlo, qhi), each (..., S).
+    """
+    desc = gk[..., 1:] < gk[..., :-1]
+    tail = jnp.cumsum(desc, axis=-1, dtype=jnp.int32) > 0
+    head = jnp.concatenate([jnp.ones_like(tail[..., :1]), ~tail], axis=-1)
+    qlo = gk[..., 0] // slice_k
+    qhi = jnp.max(jnp.where(head, gk, 0), axis=-1) // slice_k
+    return qlo.astype(jnp.int32), qhi.astype(jnp.int32)
+
+
+def gather_step(a_chunk, bt_chunk, idx: jax.Array, qlo, qhi, *,
+                bm: int, bn: int, slice_k: int, a_dtype, b_dtype):
+    """Pack one condensed step's operands out of resident panels.
+
+    ``a_chunk(q)`` → the (bm, slice_k) A columns of k-chunk q,
+    ``bt_chunk(q)`` → the (bn, slice_k) transposed B rows of chunk q.
+    Lane l of the packed step reads k = idx[l]: chunk ``idx // slice_k``,
+    offset ``idx % slice_k``.  Each chunk in [qlo, qhi] is gathered with
+    one lane gather (Mosaic gathers 32-bit data within 128 lanes, so the
+    chunk is upcast to f32) and merged into the lanes that belong to it.
+    B is gathered transposed because Mosaic gathers along lanes, not
+    across sublane tiles.  Returns (a_pack (bm, sk), bt_pack (bn, sk))
+    cast back to the panels' dtypes.
+    """
+    chunk = idx // slice_k                       # (1, sk)
+    off = idx % slice_k
+
+    def body(q, carry):
+        a_pack, bt_pack = carry
+        a_c = a_chunk(q).astype(jnp.float32)
+        bt_c = bt_chunk(q).astype(jnp.float32)
+        sel = chunk == q
+        a_g = jnp.take_along_axis(a_c, jnp.broadcast_to(off, a_c.shape),
+                                  axis=1)
+        bt_g = jnp.take_along_axis(bt_c, jnp.broadcast_to(off, bt_c.shape),
+                                   axis=1)
+        return (jnp.where(sel, a_g, a_pack), jnp.where(sel, bt_g, bt_pack))
+
+    init = (jnp.zeros((bm, slice_k), jnp.float32),
+            jnp.zeros((bn, slice_k), jnp.float32))
+    a_pack, bt_pack = jax.lax.fori_loop(qlo, qhi + 1, body, init)
+    return a_pack.astype(a_dtype), bt_pack.astype(b_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -75,22 +185,22 @@ def plan_slices(
 # kernel body
 # ---------------------------------------------------------------------------
 
-def _spgemm_kernel(idx_ref, cnt_ref, a_ref, b_ref, out_ref, acc_ref):
-    i, j, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nsteps = pl.num_programs(2)
+def _spgemm_kernel(idx_ref, cnt_ref, a_ref, b_ref, out_ref, acc_ref, *,
+                   nt: int, s: int, interpret: bool):
+    i, j, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    blk = i * nt + j
 
-    @pl.when(s == 0)
+    @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # level-1/2 skip: only active, condensed slices contribute (the
     # paper's POPC-driven OHMMA predication).
-    @pl.when(s < cnt_ref[i, j])
+    @pl.when(t < cnt_ref[blk])
     def _mac():
-        acc_ref[...] += jnp.dot(
-            a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+        acc_ref[...] += mxu_dot(a_ref[...], b_ref[...], interpret)
 
-    @pl.when(s == nsteps - 1)
+    @pl.when(t == s - 1)
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
@@ -98,6 +208,38 @@ def _spgemm_kernel(idx_ref, cnt_ref, a_ref, b_ref, out_ref, acc_ref):
 # ---------------------------------------------------------------------------
 # public entry point
 # ---------------------------------------------------------------------------
+
+def _spgemm_call(a, b, ks, counts, *, block_m, block_n, slice_k,
+                 interpret, out_dtype):
+    """One pallas_call over an (mt, nt) output-block rectangle."""
+    mt, nt, s = ks.shape
+    kernel = functools.partial(_spgemm_kernel, nt=nt, s=s,
+                               interpret=interpret)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(mt, nt, s),
+        in_specs=[
+            pl.BlockSpec((block_m, slice_k),
+                         lambda i, j, t, idx, cnt:
+                         (i, idx[(i * nt + j) * s + t])),
+            pl.BlockSpec((slice_k, block_n),
+                         lambda i, j, t, idx, cnt:
+                         (idx[(i * nt + j) * s + t], j)),
+        ],
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, t, idx, cnt: (i, j)),
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((mt * block_m, nt * block_n),
+                                       out_dtype),
+        compiler_params=_compiler_params(("parallel", "parallel",
+                                          "arbitrary")),
+        interpret=interpret,
+    )(ks.reshape(-1), counts.reshape(-1), a, b)
+
 
 @functools.partial(
     jax.jit,
@@ -128,28 +270,11 @@ def bitmap_spgemm_planned(
     a = jnp.pad(a, ((0, pad_m), (0, pad_k)))
     b = jnp.pad(b, ((0, pad_k), (0, pad_n)))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(mt, nt, s),
-        in_specs=[
-            pl.BlockSpec((block_m, slice_k),
-                         lambda i, j, t, idx, cnt: (i, idx[i, j, t])),
-            pl.BlockSpec((slice_k, block_n),
-                         lambda i, j, t, idx, cnt: (idx[i, j, t], j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n),
-                               lambda i, j, t, idx, cnt: (i, j)),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        _spgemm_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mt * block_m, nt * block_n),
-                                       out_dtype),
-        compiler_params=_compiler_params(("parallel", "parallel",
-                                          "arbitrary")),
-        interpret=interpret,
-    )(ks, counts, a, b)
+    call = functools.partial(_spgemm_call, block_m=block_m,
+                             block_n=block_n, slice_k=slice_k,
+                             interpret=interpret, out_dtype=out_dtype)
+    out = over_blocks(call, a, b, ks, counts, block_m=block_m,
+                      block_n=block_n, words_per_block=s + 1)
     return out[:m, :n]
 
 
@@ -165,13 +290,12 @@ def bitmap_spgemm(
     out_dtype=None,
 ) -> jax.Array:
     """Dual-side sparse C = A @ B with on-the-fly bitmap planning."""
+    from repro.sparse import plan as pln
     del block_k
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    # clamp blocks for small problems (tests) while keeping lane alignment
-    block_m = min(block_m, max(8, a.shape[0]))
-    block_n = min(block_n, max(128 if not interpret else 8, b.shape[1]))
-    slice_k = min(slice_k, max(8, a.shape[1]))
+    interpret = platform.resolve_interpret(interpret)
+    block_m, block_n, slice_k = pln.clamp_geometry(
+        a.shape[0], b.shape[1], a.shape[1], block_m, block_n, slice_k,
+        interpret)
     ks, counts = plan_slices(a, b, block_m, block_n, slice_k)
     return bitmap_spgemm_planned(
         a, b, ks, counts, block_m=block_m, block_n=block_n, slice_k=slice_k,
@@ -233,9 +357,11 @@ def bitmap_spgemm_kcondensed(
 # fused K-condensation (DESIGN.md §12): the schedule gathers, not a pre-pass
 # ---------------------------------------------------------------------------
 
-def _spgemm_kfused_kernel(cnt_ref, gk_ref, a_ref, b_ref, out_ref, acc_ref):
+def _spgemm_kfused_kernel(cnt_ref, qlo_ref, qhi_ref, gk_ref, a_ref, b_ref,
+                          out_ref, acc_ref, *, nt: int, s: int,
+                          slice_k: int, interpret: bool):
     i, j, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nsteps = pl.num_programs(2)
+    blk = i * nt + j
 
     @pl.when(t == 0)
     def _init():
@@ -246,17 +372,64 @@ def _spgemm_kfused_kernel(cnt_ref, gk_ref, a_ref, b_ref, out_ref, acc_ref):
     # panels, so the gather rides the block DMAs that already happened.
     # Lanes past the block's nnz reference *inactive* k's (zero outer
     # products), so the last partial step needs no lane predication.
-    @pl.when(t < cnt_ref[i, j])
+    @pl.when(t < cnt_ref[blk])
     def _mac():
-        idx = gk_ref[0, 0, 0, :]
-        a_pack = jnp.take(a_ref[...], idx, axis=1)
-        b_pack = jnp.take(b_ref[...], idx, axis=0)
-        acc_ref[...] += jnp.dot(a_pack, b_pack,
-                                preferred_element_type=jnp.float32)
+        step = blk * s + t
+        bm, bn = acc_ref.shape
 
-    @pl.when(t == nsteps - 1)
+        def a_chunk(q):
+            return a_ref[:, pl.ds(pl.multiple_of(q * slice_k, slice_k),
+                                  slice_k)]
+
+        def bt_chunk(q):
+            return b_ref[pl.ds(pl.multiple_of(q * slice_k, slice_k),
+                               slice_k), :].T
+
+        a_pack, bt_pack = gather_step(
+            a_chunk, bt_chunk, gk_ref[0, 0, pl.ds(t, 1), :],
+            qlo_ref[step], qhi_ref[step], bm=bm, bn=bn, slice_k=slice_k,
+            a_dtype=a_ref.dtype, b_dtype=b_ref.dtype)
+        acc_ref[...] += mxu_dot(a_pack, bt_pack, interpret,
+                                contract=((1,), (1,)))
+
+    @pl.when(t == s - 1)
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _kfused_call(a, b, gk, counts, *, block_m, block_n, slice_k, interpret,
+                 out_dtype):
+    """One pallas_call over an (mt, nt) output-block rectangle."""
+    mt, nt, s, _ = gk.shape
+    kp = s * slice_k
+    qlo, qhi = chunk_ranges(gk, slice_k)
+    kernel = functools.partial(_spgemm_kfused_kernel, nt=nt, s=s,
+                               slice_k=slice_k, interpret=interpret)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(mt, nt, s),
+        in_specs=[
+            # the block's whole packed schedule (S, slice_k), resident
+            # across its condensed steps; step t reads row t
+            pl.BlockSpec((1, 1, s, slice_k),
+                         lambda i, j, t, *_: (i, j, 0, 0)),
+            # operand panels: full contraction depth, resident per (i, j)
+            pl.BlockSpec((block_m, kp), lambda i, j, t, *_: (i, 0)),
+            pl.BlockSpec((kp, block_n), lambda i, j, t, *_: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, t, *_: (i, j)),
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((mt * block_m, nt * block_n),
+                                       out_dtype),
+        compiler_params=_compiler_params(("parallel", "parallel",
+                                          "arbitrary")),
+        interpret=interpret,
+    )(counts.reshape(-1), qlo.reshape(-1), qhi.reshape(-1), gk, a, b)
 
 
 @functools.partial(
@@ -283,9 +456,9 @@ def bitmap_spgemm_kfused_planned(
     element-granular skips instead of whole-k-slice quantisation.
     Operand panels stay VMEM-resident across the condensed steps
     ((block_m, K) of A per block-row, (K, block_n) of B per block-col),
-    so the packed-k gather costs no HBM traffic beyond the block DMAs
-    the dense schedule already performs (DESIGN.md §12 discusses the
-    VMEM budget and the staging-ring variant for very deep K).
+    so the packed-k gather (:func:`gather_step`) costs no HBM traffic
+    beyond the block DMAs the dense schedule already performs
+    (DESIGN.md §12 discusses the VMEM budget).
     """
     m, k = a.shape
     k2, n = b.shape
@@ -298,31 +471,11 @@ def bitmap_spgemm_kfused_planned(
     a = jnp.pad(a, ((0, mt * block_m - m), (0, kp - k)))
     b = jnp.pad(b, ((0, kp - k), (0, nt * block_n - n)))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(mt, nt, s),
-        in_specs=[
-            # per-step lane gather map (the schedule is data, not prefetch:
-            # the kernel body reads a slice_k-vector of it per grid step)
-            pl.BlockSpec((1, 1, 1, slice_k),
-                         lambda i, j, t, cnt: (i, j, t, 0)),
-            # operand panels: full contraction depth, resident per (i, j)
-            pl.BlockSpec((block_m, kp), lambda i, j, t, cnt: (i, 0)),
-            pl.BlockSpec((kp, block_n), lambda i, j, t, cnt: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n),
-                               lambda i, j, t, cnt: (i, j)),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        _spgemm_kfused_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mt * block_m, nt * block_n),
-                                       out_dtype),
-        compiler_params=_compiler_params(("parallel", "parallel",
-                                          "arbitrary")),
-        interpret=interpret,
-    )(counts, gk, a, b)
+    call = functools.partial(_kfused_call, block_m=block_m,
+                             block_n=block_n, slice_k=slice_k,
+                             interpret=interpret, out_dtype=out_dtype)
+    out = over_blocks(call, a, b, gk, counts, block_m=block_m,
+                      block_n=block_n, words_per_block=2 * s + 1)
     return out[:m, :n]
 
 
@@ -338,11 +491,10 @@ def bitmap_spgemm_kfused(
 ) -> jax.Array:
     """Fused-K-condensed C = A @ B with on-the-fly element planning."""
     from repro.sparse import plan as pln
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = platform.resolve_interpret(interpret)
     block_m, block_n, slice_k = pln.clamp_geometry(
         a.shape[0], b.shape[1], a.shape[1], block_m, block_n, slice_k,
-        bool(interpret))
+        interpret)
     kp = pln.plan_kcondensed(
         pln.element_activity_lhs(a, block_m),
         pln.element_activity_rhs(b, block_n), slice_k)

@@ -32,7 +32,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bitmap_spgemm import SLICE_K, _compiler_params
+from repro.kernels import platform
+from repro.kernels.bitmap_spgemm import (SLICE_K, _compiler_params,
+                                         chunk_ranges, gather_step,
+                                         grid_split, mxu_dot)
 
 
 # ---------------------------------------------------------------------------
@@ -62,23 +65,23 @@ def plan_grouped(
 # kernel body
 # ---------------------------------------------------------------------------
 
-def _grouped_kernel(idx_ref, cnt_ref, a_ref, b_ref, out_ref, acc_ref):
+def _grouped_kernel(idx_ref, cnt_ref, a_ref, b_ref, out_ref, acc_ref, *,
+                    mt: int, nt: int, s: int, interpret: bool):
     e = pl.program_id(0)
-    i, j, s = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    nsteps = pl.num_programs(3)
+    i, j, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    blk = (e * mt + i) * nt + j
 
-    @pl.when(s == 0)
+    @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # level-1/2 skip: only this expert's active, condensed slices
     # contribute; ragged-empty blocks have cnt == 0 and do no MXU work.
-    @pl.when(s < cnt_ref[e, i, j])
+    @pl.when(t < cnt_ref[blk])
     def _mac():
-        acc_ref[...] += jnp.dot(
-            a_ref[0], b_ref[0], preferred_element_type=jnp.float32)
+        acc_ref[...] += mxu_dot(a_ref[0], b_ref[0], interpret)
 
-    @pl.when(s == nsteps - 1)
+    @pl.when(t == s - 1)
     def _flush():
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
@@ -86,6 +89,14 @@ def _grouped_kernel(idx_ref, cnt_ref, a_ref, b_ref, out_ref, acc_ref):
 # ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------
+
+def _by_experts(call, e: int, words_per_expert: int, *arrays):
+    """Run ``call`` over expert ranges whose schedules fit SMEM and
+    concatenate the (E, ...) outputs."""
+    per_call, _ = grid_split(e, 1, words_per_expert)
+    return jnp.concatenate([call(*(x[e0:e0 + per_call] for x in arrays))
+                            for e0 in range(0, e, per_call)], axis=0)
+
 
 @functools.partial(
     jax.jit,
@@ -122,30 +133,40 @@ def grouped_spgemm_planned(
     a = jnp.pad(a, ((0, 0), (0, pad_m), (0, pad_k)))
     b = jnp.pad(b, ((0, 0), (0, pad_k), (0, pad_n)))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(e, mt, nt, s),
-        in_specs=[
-            pl.BlockSpec((1, block_m, slice_k),
-                         lambda g, i, j, t, idx, cnt:
-                         (g, i, idx[g, i, j, t])),
-            pl.BlockSpec((1, slice_k, block_n),
-                         lambda g, i, j, t, idx, cnt:
-                         (g, idx[g, i, j, t], j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_m, block_n),
-                               lambda g, i, j, t, idx, cnt: (g, i, j)),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        _grouped_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (e, mt * block_m, nt * block_n), out_dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(ks, counts, a, b)
+    def call(a, b, ks, counts):
+        ec = ks.shape[0]
+        kernel = functools.partial(_grouped_kernel, mt=mt, nt=nt, s=s,
+                                   interpret=interpret)
+
+        def step(g, i, j, t, idx):
+            return idx[((g * mt + i) * nt + j) * s + t]
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(ec, mt, nt, s),
+            in_specs=[
+                pl.BlockSpec((1, block_m, slice_k),
+                             lambda g, i, j, t, idx, cnt:
+                             (g, i, step(g, i, j, t, idx))),
+                pl.BlockSpec((1, slice_k, block_n),
+                             lambda g, i, j, t, idx, cnt:
+                             (g, step(g, i, j, t, idx), j)),
+            ],
+            out_specs=pl.BlockSpec((1, block_m, block_n),
+                                   lambda g, i, j, t, idx, cnt: (g, i, j)),
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (ec, mt * block_m, nt * block_n), out_dtype),
+            compiler_params=_compiler_params(
+                ("parallel", "parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(ks.reshape(-1), counts.reshape(-1), a, b)
+
+    out = _by_experts(call, e, mt * nt * (s + 1), a, b, ks, counts)
     return out[:, :c, :n]
 
 
@@ -161,12 +182,11 @@ def grouped_spgemm(
 ) -> jax.Array:
     """Ragged grouped SpGEMM with on-the-fly per-expert planning."""
     from repro.sparse import plan as pln
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = platform.resolve_interpret(interpret)
     e, c, k = a.shape
     n = b.shape[-1]
     block_m, block_n, slice_k = pln.clamp_geometry(
-        c, n, k, block_m, block_n, slice_k, bool(interpret))
+        c, n, k, block_m, block_n, slice_k, interpret)
     ks, counts = plan_grouped(a, b, block_m, block_n, slice_k)
     return grouped_spgemm_planned(
         a, b, ks, counts, block_m=block_m, block_n=block_n,
@@ -177,10 +197,12 @@ def grouped_spgemm(
 # fused K-condensation (DESIGN.md §12): per-expert packed-k schedules
 # ---------------------------------------------------------------------------
 
-def _grouped_kfused_kernel(cnt_ref, gk_ref, a_ref, b_ref, out_ref, acc_ref):
+def _grouped_kfused_kernel(cnt_ref, qlo_ref, qhi_ref, gk_ref, a_ref, b_ref,
+                           out_ref, acc_ref, *, mt: int, nt: int, s: int,
+                           slice_k: int, interpret: bool):
     e = pl.program_id(0)
     i, j, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    nsteps = pl.num_programs(3)
+    blk = (e * mt + i) * nt + j
 
     @pl.when(t == 0)
     def _init():
@@ -189,15 +211,27 @@ def _grouped_kfused_kernel(cnt_ref, gk_ref, a_ref, b_ref, out_ref, acc_ref):
     # element-granular condensation per expert: step t gathers its
     # packed k's from the expert's VMEM-resident operand panels; lanes
     # past the block's nnz reference inactive k's (zero outer products).
-    @pl.when(t < cnt_ref[e, i, j])
+    @pl.when(t < cnt_ref[blk])
     def _mac():
-        idx = gk_ref[0, 0, 0, 0, :]
-        a_pack = jnp.take(a_ref[0], idx, axis=1)
-        b_pack = jnp.take(b_ref[0], idx, axis=0)
-        acc_ref[...] += jnp.dot(a_pack, b_pack,
-                                preferred_element_type=jnp.float32)
+        step = blk * s + t
+        bm, bn = acc_ref.shape
 
-    @pl.when(t == nsteps - 1)
+        def a_chunk(q):
+            return a_ref[0, :, pl.ds(pl.multiple_of(q * slice_k, slice_k),
+                                     slice_k)]
+
+        def bt_chunk(q):
+            return b_ref[0, pl.ds(pl.multiple_of(q * slice_k, slice_k),
+                                  slice_k), :].T
+
+        a_pack, bt_pack = gather_step(
+            a_chunk, bt_chunk, gk_ref[0, 0, 0, pl.ds(t, 1), :],
+            qlo_ref[step], qhi_ref[step], bm=bm, bn=bn, slice_k=slice_k,
+            a_dtype=a_ref.dtype, b_dtype=b_ref.dtype)
+        acc_ref[...] += mxu_dot(a_pack, bt_pack, interpret,
+                                contract=((1,), (1,)))
+
+    @pl.when(t == s - 1)
     def _flush():
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
@@ -239,30 +273,38 @@ def grouped_spgemm_kfused_planned(
     a = jnp.pad(a, ((0, 0), (0, mt * block_m - c), (0, kp - k)))
     b = jnp.pad(b, ((0, 0), (0, kp - k), (0, nt * block_n - n)))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(e, mt, nt, s),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, 1, slice_k),
-                         lambda g, i, j, t, cnt: (g, i, j, t, 0)),
-            pl.BlockSpec((1, block_m, kp),
-                         lambda g, i, j, t, cnt: (g, i, 0)),
-            pl.BlockSpec((1, kp, block_n),
-                         lambda g, i, j, t, cnt: (g, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_m, block_n),
-                               lambda g, i, j, t, cnt: (g, i, j)),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        _grouped_kfused_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (e, mt * block_m, nt * block_n), out_dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(counts, gk, a, b)
+    def call(a, b, gk, counts):
+        ec = gk.shape[0]
+        qlo, qhi = chunk_ranges(gk, slice_k)
+        kernel = functools.partial(_grouped_kfused_kernel, mt=mt, nt=nt,
+                                   s=s, slice_k=slice_k,
+                                   interpret=interpret)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(ec, mt, nt, s),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1, s, slice_k),
+                             lambda g, i, j, t, *_: (g, i, j, 0, 0)),
+                pl.BlockSpec((1, block_m, kp),
+                             lambda g, i, j, t, *_: (g, i, 0)),
+                pl.BlockSpec((1, kp, block_n),
+                             lambda g, i, j, t, *_: (g, 0, j)),
+            ],
+            out_specs=pl.BlockSpec((1, block_m, block_n),
+                                   lambda g, i, j, t, *_: (g, i, j)),
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (ec, mt * block_m, nt * block_n), out_dtype),
+            compiler_params=_compiler_params(
+                ("parallel", "parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(counts.reshape(-1), qlo.reshape(-1), qhi.reshape(-1), gk, a, b)
+
+    out = _by_experts(call, e, mt * nt * (2 * s + 1), a, b, gk, counts)
     return out[:, :c, :n]
 
 
@@ -278,12 +320,11 @@ def grouped_spgemm_kfused(
 ) -> jax.Array:
     """Fused-K-condensed grouped SpGEMM with on-the-fly planning."""
     from repro.sparse import plan as pln
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = platform.resolve_interpret(interpret)
     e, c, k = a.shape
     n = b.shape[-1]
     block_m, block_n, slice_k = pln.clamp_geometry(
-        c, n, k, block_m, block_n, slice_k, bool(interpret))
+        c, n, k, block_m, block_n, slice_k, interpret)
     kp = pln.plan_grouped_kcondensed(
         jax.vmap(lambda ai: pln.element_activity_lhs(ai, block_m))(a),
         jax.vmap(lambda bi: pln.element_activity_rhs(bi, block_n))(b),

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the
-kernel body executes as jnp ops, which is the validation path; on TPU they
-compile to Mosaic.  ``interpret=None`` auto-detects.
+Off the TPU kernels run in ``interpret=True`` mode — the kernel body
+executes as jnp ops, which is the validation path; on the TPU they
+compile to Mosaic.  ``interpret=None`` resolves through
+:func:`repro.kernels.platform.resolve_interpret`.
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 from repro.core import bitmap as bmod
 from repro.core import im2col as i2c
 from repro.kernels.bitmap_encode import bitmap_encode_pallas
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.bitmap_spgemm import (  # noqa: F401  (re-exports)
     bitmap_spgemm,
     bitmap_spgemm_kcondensed,
@@ -23,21 +25,12 @@ from repro.kernels.bitmap_spgemm import (  # noqa: F401  (re-exports)
     kcondense,
     plan_slices,
 )
-from repro.kernels.sparse_im2col import (
-    sparse_im2col_pallas,
-    sparse_im2col_strided_pallas,
-)
-
-
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() == "cpu"
-    return bool(interpret)
+from repro.kernels.sparse_im2col import sparse_im2col_pallas
 
 
 def bitmap_encode(x: jax.Array, interpret: Optional[bool] = None):
     """(C, H, W) dense → (packed bits, row-condensed values)."""
-    return bitmap_encode_pallas(x, interpret=_auto_interpret(interpret))
+    return bitmap_encode_pallas(x, interpret=resolve_interpret(interpret))
 
 
 def rowpacked_to_flat(low_bits: jax.Array, low_vals: jax.Array,
@@ -65,20 +58,15 @@ def sparse_im2col(
 ) -> i2c.LoweredBitmap:
     """Implicit bitmap im2col of an (H, W, C) feature map.
 
-    stride==1 runs the fused Pallas fast path (encode kernel → word
-    shift/or im2col kernel); stride≥2 runs the strided one-hot-selection
-    kernel variant — same encode, same outputs, every stride counted.
+    The encode kernel condenses each feature-map row; the im2col kernel
+    lowers every (dy, dx, c) window row from that form, any stride.
     """
-    interp = _auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     h, w, c = x.shape
     oh, ow = i2c.out_size(h, kh, stride), i2c.out_size(w, kw, stride)
     p = oh * ow
     xc = jnp.moveaxis(x, -1, 0)                        # (C, H, W)
     bits, cond = bitmap_encode_pallas(xc, interpret=interp)
-    if stride == 1:
-        low_bits, low_vals = sparse_im2col_pallas(
-            cond, bits, kh=kh, kw=kw, interpret=interp)
-    else:
-        low_bits, low_vals = sparse_im2col_strided_pallas(
-            cond, bits, kh=kh, kw=kw, stride=stride, interpret=interp)
+    low_bits, low_vals = sparse_im2col_pallas(
+        cond, bits, kh=kh, kw=kw, stride=stride, interpret=interp)
     return rowpacked_to_flat(low_bits, low_vals, ow, p)

@@ -1,23 +1,24 @@
 """Pallas TPU kernel: bitmap-based implicit sparse im2col (paper Fig. 11).
 
 One grid program per lowered row k = (dy, dx, c).  The program reads the
-packed bitmap words and the row-condensed values of feature-map rows
-dy..dy+OH-1 (already in VMEM — the "registers" of the paper's S1), then:
+packed bitmap words and the row-condensed values of channel c (already in
+VMEM — the "registers" of the paper's S1), then:
 
-  S2  extracts the window bits by word shift/or (the paper's mask+shift),
-  S3  computes value offsets from cumulative popcounts (the accumulated
-      shifted-out bits),
-  S4  popcounts the window and gathers the condensed value segments with
-      dynamic slices, emitting the lowered row directly in condensed form.
+  S2  expands the bitmap words to lanes and decodes the condensed values
+      back to their columns (one-hot selection by cumulative popcount —
+      the accumulated shifted-out bits),
+  S3  selects the window: feature rows ``oy*stride + dy`` and columns
+      ``ox*stride + dx``, by one-hot selection matmuls,
+  S4  emits the lowered row directly in (bitmap, condensed values) form:
+      the window bits packed per output row, the values front-packed by
+      their running popcount offset.
 
 The lowered matrix never exists in HBM (implicit im2col); the outputs are
 exactly the (bitmap, condensed values) operand the SpGEMM kernel's planner
-consumes.  Kernel fast-path is stride=1 (the dominant DNN case and the
-paper's running example); strides ≥ 2 (whisper's second stem conv, patch
-convs) run the strided variant below, which trades the word shift/or for
-one-hot row/column selection matmuls (gather-free, Mosaic-friendly) over
-the unpacked window — same output contract, so ``ops.py`` shares the
-flat-P conversion.
+consumes.  Every data-dependent gather is a one-hot matmul on 128 x 128
+tiles and every prefix count a lane-rotation scan, because Mosaic lowers
+neither gathers at data-dependent lane offsets nor cumsum; all strides
+share the one kernel.
 
 Output bitmap layout: per-output-row packed words, i.e. shape
 (KKC, OH, ceil(OW/32)) — each feature row's window bits start a fresh word
@@ -35,222 +36,168 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitmap import WORD
+from repro.kernels.bitmap_encode import (LANES, lane_prefix_sum,
+                                         pack_words)
+
+_EXACT = jax.lax.Precision.HIGHEST
 
 
-def _im2col_kernel(vals_ref, bits_ref, out_bits_ref, out_vals_ref, *,
-                   oh: int, ow: int, oww: int):
+def _mm(a, b, contract=((1,), (0,))):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=_EXACT,
+                               preferred_element_type=jnp.float32)
+
+
+def _unpack_words(words: jax.Array, w: int) -> jax.Array:
+    """(H, W/32) uint32 → (H, W) bool, LSB-first: each word is spread to
+    its 32 lanes by a 0/1 matmul, one 16-bit half at a time (exact)."""
+    ww = words.shape[1]
+    wi = jax.lax.bitcast_convert_type(words, jnp.int32)
+    own = (jax.lax.broadcasted_iota(jnp.int32, (ww, w), 1) // WORD
+           == jax.lax.broadcasted_iota(jnp.int32, (ww, w), 0))
+    spread = own.astype(jnp.float32)
+    bit = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) % WORD
+    lo = _mm((wi & 0xFFFF).astype(jnp.float32), spread).astype(jnp.int32)
+    hi = _mm(((wi >> 16) & 0xFFFF).astype(jnp.float32),
+             spread).astype(jnp.int32)
+    half = jnp.where(bit < 16, lo >> bit, hi >> jnp.maximum(bit - 16, 0))
+    return (half & 1) == 1
+
+
+def _im2col_kernel(vals_ref, bits_ref, out_bits_ref, out_vals_ref,
+                   pos_in_ref, dense_ref, low_ref, pos_ref, acc_ref, *,
+                   h: int,
+                   oh: int, ow: int, oww: int, stride: int):
     dy = pl.program_id(1)
     dx = pl.program_id(2)
+    wp = vals_ref.shape[2]
+    owp = low_ref.shape[1]
+    n_in, n_out, n_p = wp // LANES, owp // LANES, acc_ref.shape[1] // LANES
+    t_rows = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    t_cols = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
 
-    # slice-only ref indexers (interpret-mode discharge rejects bare ints)
-    vals_rows = pl.load(
-        vals_ref, (pl.ds(0, 1), pl.ds(dy, oh), slice(None)))[0]
-    words = pl.load(
-        bits_ref, (pl.ds(0, 1), pl.ds(dy, oh), slice(None)))[0]
+    # ---- S2: bitmap words → lanes; condensed values → their columns ----
+    m = _unpack_words(bits_ref[0], wp).astype(jnp.int32)    # (H, Wp)
+    # offset of each set bit's value in its row (-1: no value)
+    pos_in_ref[...] = jnp.where(m == 1, lane_prefix_sum(m) - m, -1)
+    for y in range(h):
+        def decode(j, _, y=y):
+            cols = pl.ds(pl.multiple_of(j * LANES, LANES), LANES)
 
-    q = (dx // WORD).astype(jnp.int32)
-    r = (dx % WORD).astype(jnp.uint32)
+            def gather(t, acc):
+                src = vals_ref[0, y:y + 1, pl.ds(
+                    pl.multiple_of(t * LANES, LANES), LANES)]
+                # sel[t', j'] = [c(j) = 128T + t' ∧ m(j)]
+                sel = pos_in_ref[y:y + 1, cols] == t * LANES + t_rows
+                return acc + _mm(src.astype(jnp.float32),
+                                 sel.astype(jnp.float32))
 
-    # ---- S2: window bit extraction (mask + shift on the bitmap row) ----
-    wq = jax.lax.dynamic_slice(words, (0, q), (oh, oww + 1))
-    lo = wq[:, :oww] >> r
-    hi = jnp.where(r == 0, jnp.uint32(0),
-                   wq[:, 1:] << (jnp.uint32(WORD) - r))
-    lowered = lo | hi                                 # (OH, OWw)
-    tail = ow % WORD
-    if tail:
-        lane = jax.lax.broadcasted_iota(jnp.int32, (oh, oww), 1)
-        tail_mask = jnp.where(lane == oww - 1,
-                              jnp.uint32((1 << tail) - 1),
-                              jnp.uint32(0xFFFFFFFF))
-        lowered = lowered & tail_mask
-    out_bits_ref[...] = lowered[None]
+            dense_ref[y:y + 1, cols] = jax.lax.fori_loop(
+                0, j + 1, gather, jnp.zeros((1, LANES), jnp.float32))
+            return 0
 
-    # ---- S3: offsets = accumulated shifted-out popcount ----
-    pc = jax.lax.population_count(words).astype(jnp.int32)   # (OH, Wwp)
-    prefix = jnp.cumsum(pc, axis=1) - pc                      # exclusive
-    off_word = jax.lax.dynamic_slice(prefix, (0, q), (oh, 1))[:, 0]
-    in_word = jax.lax.population_count(
-        wq[:, 0] & ((jnp.uint32(1) << r) - jnp.uint32(1))).astype(jnp.int32)
-    offs = off_word + in_word                                 # (OH,)
+        jax.lax.fori_loop(0, n_in, decode, 0)
 
-    # ---- S4: popcount window lengths + condensed value gather ----
-    seg_lens = jnp.sum(jax.lax.population_count(lowered).astype(jnp.int32),
-                       axis=1)                                # (OH,)
-    out_vals_ref[...] = jnp.zeros_like(out_vals_ref)
-    lane = jax.lax.iota(jnp.int32, ow)
+    # ---- S3: window rows oy*stride + dy, then columns ox*stride + dx ----
+    row_sel = (jax.lax.broadcasted_iota(jnp.int32, (oh, h), 0) * stride
+               + dy == jax.lax.broadcasted_iota(jnp.int32, (oh, h), 1))
+    rows = _mm(row_sel.astype(jnp.float32), dense_ref[...])  # (OH, Wp)
+    dense_ref[0:oh, :] = rows
 
-    def body(oy, off_run):
-        start = jax.lax.dynamic_slice(offs, (oy,), (1,))[0]
-        seg = jax.lax.dynamic_slice(vals_rows, (oy, start), (1, ow))[0]
-        ln = jax.lax.dynamic_slice(seg_lens, (oy,), (1,))[0]
-        seg = jnp.where(lane < ln, seg, 0)
-        pl.store(out_vals_ref, (pl.ds(0, 1), pl.ds(off_run, ow)), seg[None])
-        return off_run + ln
+    def columns(x, _):
+        def gather(j, acc):
+            src = dense_ref[0:oh, pl.ds(pl.multiple_of(j * LANES, LANES),
+                                        LANES)]
+            ox = x * LANES + t_cols
+            sel = (j * LANES + t_rows == ox * stride + dx) & (ox < ow)
+            return acc + _mm(src, sel.astype(jnp.float32))
 
-    jax.lax.fori_loop(0, oh, body, jnp.int32(0))
+        lo = x * stride
+        hi = jnp.minimum(lo + stride + 1, n_in)
+        low_ref[:, pl.ds(pl.multiple_of(x * LANES, LANES), LANES)] = (
+            jax.lax.fori_loop(lo, hi, gather,
+                              jnp.zeros((oh, LANES), jnp.float32)))
+        return 0
 
+    jax.lax.fori_loop(0, n_out, columns, 0)
 
-def _im2col_kernel_strided(vals_ref, bits_ref, out_bits_ref, out_vals_ref,
-                           *, h: int, oh: int, ow: int, oww: int,
-                           stride: int):
-    dy = pl.program_id(1)
-    dx = pl.program_id(2)
+    # ---- S4: window bits per output row; values by popcount offset ----
+    low = low_ref[...]                                       # (OH, OWp)
+    active = low != 0
+    out_bits_ref[0] = pack_words(active)[:, :oww]
+    a = active.astype(jnp.int32)
+    # non-zeros in the rows above each output row
+    before = (jax.lax.broadcasted_iota(jnp.int32, (oh, oh), 1)
+              < jax.lax.broadcasted_iota(jnp.int32, (oh, oh), 0))
+    row_off = jnp.sum(_mm(before.astype(jnp.float32),
+                          a.astype(jnp.float32)), axis=1,
+                      keepdims=True).astype(jnp.int32)       # (OH, 1)
+    pos_ref[...] = jnp.where(active, row_off + lane_prefix_sum(a) - a, -1)
 
-    vals_rows = pl.load(
-        vals_ref, (pl.ds(0, 1), slice(None), slice(None)))[0]  # (H, Wp)
-    words = pl.load(
-        bits_ref, (pl.ds(0, 1), slice(None), slice(None)))[0]  # (H, Wwp)
-    wwp = words.shape[1]
-    wp = vals_rows.shape[1]
+    def emit(t, _):
+        def row(oy, acc):
+            def gather(x, acc):
+                cols = pl.ds(pl.multiple_of(x * LANES, LANES), LANES)
+                src = low_ref[pl.ds(oy, 1), cols]
+                # selᵀ[t', x'] = [pos(oy, x) = 128T + t']
+                sel_t = pos_ref[pl.ds(oy, 1), cols] == t * LANES + t_rows
+                return acc + _mm(src, sel_t.astype(jnp.float32),
+                                 contract=((1,), (1,)))
 
-    # ---- S2: unpack the bitmap row and select the strided window ----
-    # (strided bits are not word-contiguous, so instead of shift/or we
-    # unpack and select via one-hot matmuls — no data-dependent gathers)
-    shifts = jax.lax.broadcasted_iota(
-        jnp.int32, (h, wwp, WORD), 2).astype(jnp.uint32)
-    bits_full = ((words[:, :, None] >> shifts) & jnp.uint32(1)
-                 ).reshape(h, wwp * WORD).astype(jnp.float32)  # (H, Wb)
-    # S3 offsets: exclusive popcount prefix per feature-map row
-    offs_full = jnp.cumsum(bits_full, axis=1) - bits_full      # (H, Wb)
+            return jax.lax.fori_loop(0, n_out, gather, acc)
 
-    # row one-hot: output row oy reads feature row oy*stride + dy
-    oy_i = jax.lax.broadcasted_iota(jnp.int32, (oh, h), 0)
-    yy_i = jax.lax.broadcasted_iota(jnp.int32, (oh, h), 1)
-    row_sel = (oy_i * stride + dy == yy_i).astype(jnp.float32)  # (OH, H)
-    mask_rows = jnp.dot(row_sel, bits_full)                     # (OH, Wb)
-    offs_rows = jnp.dot(row_sel, offs_full)                     # (OH, Wb)
-    vals_sel = jnp.dot(row_sel, vals_rows.astype(jnp.float32))  # (OH, Wp)
+        acc_ref[:, pl.ds(pl.multiple_of(t * LANES, LANES), LANES)] = (
+            jax.lax.fori_loop(0, oh, row,
+                              jnp.zeros((1, LANES), jnp.float32)))
+        return 0
 
-    # column one-hot: output col ox reads pixel ox*stride + dx
-    wb = wwp * WORD
-    cc_i = jax.lax.broadcasted_iota(jnp.int32, (wb, ow), 0)
-    ox_i = jax.lax.broadcasted_iota(jnp.int32, (wb, ow), 1)
-    col_sel = (ox_i * stride + dx == cc_i).astype(jnp.float32)  # (Wb, OW)
-    bits_w = jnp.dot(mask_rows, col_sel)                        # (OH, OW)
-    offs_w = jnp.dot(offs_rows, col_sel).astype(jnp.int32)      # (OH, OW)
-    active = bits_w > 0.5
-
-    # ---- S4: one-hot gather of the condensed values by offset ----
-    tgt = jax.lax.broadcasted_iota(jnp.int32, (oh, ow, wp), 2)
-    g = ((offs_w[:, :, None] == tgt) & active[:, :, None]
-         ).astype(jnp.float32)
-    vals_w = jnp.sum(g * vals_sel[:, None, :], axis=2)          # (OH, OW)
-
-    # per-output-row condense (rank one-hot scatter) + packed bits
-    act_i = active.astype(jnp.int32)
-    rank = jnp.cumsum(act_i, axis=1) - act_i                    # (OH, OW)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (oh, ow, ow), 2)
-    scat = ((rank[:, :, None] == slot) & active[:, :, None]
-            ).astype(jnp.float32)
-    seg = jnp.sum(vals_w[:, :, None] * scat, axis=1)            # (OH, OW)
-    seg_lens = jnp.sum(act_i, axis=1)                           # (OH,)
-
-    pad = oww * WORD - ow
-    bits_pad = jnp.pad(act_i, ((0, 0), (0, pad)))
-    weights = (jnp.uint32(1) << jax.lax.broadcasted_iota(
-        jnp.int32, (oh, oww, WORD), 2).astype(jnp.uint32))
-    out_bits_ref[...] = jnp.sum(
-        bits_pad.reshape(oh, oww, WORD).astype(jnp.uint32) * weights,
-        axis=2, dtype=jnp.uint32)[None]
-
-    out_vals_ref[...] = jnp.zeros_like(out_vals_ref)
-    dtype = out_vals_ref.dtype
-
-    def body(oy, off_run):
-        s_row = jax.lax.dynamic_slice(seg, (oy, 0), (1, ow))[0]
-        ln = jax.lax.dynamic_slice(seg_lens, (oy,), (1,))[0]
-        pl.store(out_vals_ref, (pl.ds(0, 1), pl.ds(off_run, ow)),
-                 s_row.astype(dtype)[None])
-        return off_run + ln
-
-    jax.lax.fori_loop(0, oh, body, jnp.int32(0))
+    jax.lax.fori_loop(0, n_p, emit, 0)
+    out_vals_ref[0] = acc_ref[...].astype(out_vals_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("kh", "kw", "stride", "interpret"))
-def sparse_im2col_strided_pallas(
+def sparse_im2col_pallas(
     cond_vals: jax.Array,   # (C, H, W) row-condensed values
     bits: jax.Array,        # (C, H, ceil(W/32)) packed uint32
-    *, kh: int, kw: int, stride: int, interpret: bool = False,
+    *, kh: int, kw: int, stride: int = 1, interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Strided variant, same output contract as :func:`sparse_im2col_pallas`.
-
-    Returns (lowered_bits (KKC, OH, OWw) uint32, lowered_vals (KKC, P)).
-    """
+    """Returns (lowered_bits (KKC, OH, OWw) uint32, lowered_vals (KKC, P))."""
     c, h, w = cond_vals.shape
     oh, ow = (h - kh) // stride + 1, (w - kw) // stride + 1
     oww = -(-ow // WORD)
     p = oh * ow
-    p_cap = -(-(p + ow) // 128) * 128  # slack for the last dynamic store
-
-    vals_p = jnp.pad(cond_vals, ((0, 0), (0, 0), (0, ow)))
-    wp = vals_p.shape[2]
-    wwp = bits.shape[2]
+    wp = -(-w // LANES) * LANES
+    owp = -(-ow // LANES) * LANES
+    p_cap = -(-p // LANES) * LANES
+    vals_p = jnp.pad(cond_vals, ((0, 0), (0, 0), (0, wp - w)))
+    bits_p = jnp.pad(bits, ((0, 0), (0, 0), (0, wp // WORD - bits.shape[2])))
     kkc = kh * kw * c
 
-    kernel = functools.partial(_im2col_kernel_strided, h=h, oh=oh, ow=ow,
-                               oww=oww, stride=stride)
+    kernel = functools.partial(_im2col_kernel, h=h, oh=oh, ow=ow, oww=oww,
+                               stride=stride)
     out_bits, out_vals = pl.pallas_call(
         kernel,
         grid=(c, kh, kw),
         in_specs=[
             pl.BlockSpec((1, h, wp), lambda ci, dy, dx: (ci, 0, 0)),
-            pl.BlockSpec((1, h, wwp), lambda ci, dy, dx: (ci, 0, 0)),
+            pl.BlockSpec((1, h, wp // WORD), lambda ci, dy, dx: (ci, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, oh, oww),
                          lambda ci, dy, dx: ((dy * kw + dx) * c + ci, 0, 0)),
-            pl.BlockSpec((1, p_cap),
-                         lambda ci, dy, dx: ((dy * kw + dx) * c + ci, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((kkc, oh, oww), jnp.uint32),
-            jax.ShapeDtypeStruct((kkc, p_cap), cond_vals.dtype),
-        ],
-        interpret=interpret,
-    )(vals_p, bits)
-    return out_bits, out_vals[:, :p]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("kh", "kw", "interpret"))
-def sparse_im2col_pallas(
-    cond_vals: jax.Array,   # (C, H, W) row-condensed values
-    bits: jax.Array,        # (C, H, ceil(W/32)) packed uint32
-    *, kh: int, kw: int, interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
-    """Returns (lowered_bits (KKC, OH, OWw) uint32, lowered_vals (KKC, P))."""
-    c, h, w = cond_vals.shape
-    oh, ow = h - kh + 1, w - kw + 1
-    oww = -(-ow // WORD)
-    p = oh * ow
-    p_cap = -(-(p + ow) // 128) * 128  # slack for the last dynamic store
-
-    vals_p = jnp.pad(cond_vals, ((0, 0), (0, 0), (0, ow)))
-    bits_p = jnp.pad(bits, ((0, 0), (0, 0), (0, 1)))
-    wp = vals_p.shape[2]
-    wwp = bits_p.shape[2]
-    kkc = kh * kw * c
-
-    kernel = functools.partial(_im2col_kernel, oh=oh, ow=ow, oww=oww)
-    out_bits, out_vals = pl.pallas_call(
-        kernel,
-        grid=(c, kh, kw),
-        in_specs=[
-            pl.BlockSpec((1, h, wp), lambda ci, dy, dx: (ci, 0, 0)),
-            pl.BlockSpec((1, h, wwp), lambda ci, dy, dx: (ci, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, oh, oww),
+            pl.BlockSpec((1, 1, p_cap),
                          lambda ci, dy, dx: ((dy * kw + dx) * c + ci, 0, 0)),
-            pl.BlockSpec((1, p_cap),
-                         lambda ci, dy, dx: ((dy * kw + dx) * c + ci, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((kkc, oh, oww), jnp.uint32),
-            jax.ShapeDtypeStruct((kkc, p_cap), cond_vals.dtype),
+            jax.ShapeDtypeStruct((kkc, 1, p_cap), cond_vals.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((h, wp), jnp.int32),
+                        pltpu.VMEM((h, wp), jnp.float32),
+                        pltpu.VMEM((oh, owp), jnp.float32),
+                        pltpu.VMEM((oh, owp), jnp.int32),
+                        pltpu.VMEM((1, p_cap), jnp.float32)],
         interpret=interpret,
     )(vals_p, bits_p)
-    return out_bits, out_vals[:, :p]
+    return out_bits, out_vals[:, 0, :p]

@@ -4,16 +4,19 @@ Decode latency on real hardware is dominated by exposed communication:
 the weight all-gathers and activation all-reduces of the decode mesh sit
 on the critical path unless XLA's latency-hiding scheduler overlaps them
 with compute and the collectives themselves run asynchronously on a
-prioritized stream.  These are process-level XLA options, not per-jit
-ones, so they must reach ``XLA_FLAGS`` *before the backend initializes*
-— the launch entry points apply them first thing, gated behind
-``RunConfig.latency_flags`` / ``--latency-flags``.
+prioritized stream.  These are process-level options, not per-jit ones,
+so they must reach the environment *before the backend initializes* —
+the launch entry points apply them first thing, gated behind
+``RunConfig.latency_flags`` / ``--latency-flags``.  The TPU set belongs
+to libtpu and goes to ``LIBTPU_INIT_ARGS``; the others go to
+``XLA_FLAGS``.
 
 :func:`apply_latency_flags` is additive and idempotent: it appends only
 the flags not already present, preserving whatever the environment set
-(e.g. ``--xla_force_host_platform_device_count`` for host meshes), and
-returns the resulting flag string so a dryrun test can assert the flags
-actually reach the XLA options.
+(e.g. ``--xla_force_host_platform_device_count`` for host meshes, or the
+machine's own ``LIBTPU_INIT_ARGS``), and returns the resulting flag
+string so a dryrun test can assert the flags actually reach the
+options.
 """
 from __future__ import annotations
 
@@ -50,6 +53,11 @@ def latency_flags(platform: str) -> Tuple[str, ...]:
     return LATENCY_FLAGS.get(platform, ())
 
 
+def flags_variable(platform: str) -> str:
+    """The environment variable ``platform``'s flags are read from."""
+    return "LIBTPU_INIT_ARGS" if platform == "tpu" else "XLA_FLAGS"
+
+
 def resolve_platform(env: Mapping[str, str]) -> str:
     """Which platform this process will run on, *without* initializing
     the backend: the ``JAX_PLATFORMS``/``JAX_PLATFORM_NAME`` hint if
@@ -79,14 +87,15 @@ def _backend_initialized() -> bool:
 def apply_latency_flags(platform: Optional[str] = None, *,
                         env: Optional[MutableMapping[str, str]] = None
                         ) -> str:
-    """Append the latency flags to ``env['XLA_FLAGS']`` (idempotent).
+    """Append the latency flags to the platform's flag variable
+    (:func:`flags_variable`; idempotent).
 
     Must run before the XLA backend initializes; once a backend exists
     the options are baked and this warns instead of silently having no
     effect.  ``platform`` defaults to :func:`resolve_platform` — only
     the running platform's flags are ever applied, because XLA aborts
-    on options its build doesn't register.  Returns the resulting
-    ``XLA_FLAGS`` value.
+    on options its build doesn't register.  Returns the resulting value
+    of that variable.
     """
     if env is None:
         env = os.environ
@@ -104,9 +113,10 @@ def apply_latency_flags(platform: Optional[str] = None, *,
                 "before backend init (set JAX_PLATFORMS or pass "
                 "platform=...) — applying no flags",
                 RuntimeWarning, stacklevel=2)
-    current = env.get("XLA_FLAGS", "")
+    var = flags_variable(platform)
+    current = env.get(var, "")
     present = set(current.split())
     added = [f for f in latency_flags(platform) if f not in present]
     merged = " ".join(filter(None, [current.strip()] + added))
-    env["XLA_FLAGS"] = merged
+    env[var] = merged
     return merged

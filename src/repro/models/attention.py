@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.platform import resolve_interpret
 from repro.models import cache as kvc
 from repro.models import nn
 from repro import sparse as sp
@@ -259,8 +260,12 @@ def attend_sparse(q: jax.Array, cache, cfg: ModelConfig, *,
     st_v = sp.site.make("attn.value", "attn.value", out_dtype="float32")
     kw_s = sp.site.resolve(st_s, cfg, m=t, n=g, k=hd, e=ne, dtype=q.dtype)
     kw_v = sp.site.resolve(st_v, cfg, m=g, n=hd, k=t, e=ne, dtype=q.dtype)
-    bt = pln.effective_slice_k(t, kw_v["slice_k"])
-    sk_hd = pln.effective_slice_k(hd, kw_s["slice_k"])
+    # operand metadata at the granularity the dispatch will run
+    interp = resolve_interpret(None)
+    bt = pln.clamp_geometry(g, hd, t, kw_v["block_m"], kw_v["block_n"],
+                            kw_v["slice_k"], interp)[2]
+    sk_hd = pln.clamp_geometry(t, g, hd, kw_s["block_m"], kw_s["block_n"],
+                               kw_s["slice_k"], interp)[2]
 
     x_k = skvc.score_operand(kd_e, sched_e, sk_hd)
     scores_t, _ = sp.site.grouped_matmul(x_k, qw, st_s, cfg,

@@ -45,10 +45,12 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import sparse
 from repro.configs.base import ModelConfig, RunConfig, ServeConfig
 from repro.models import model_zoo as zoo
+from repro.models import nn
 from repro.models import ssm as ssmm
 from repro.models import transformer as tfm
 from repro.serving.scheduler import PageAllocator, Scheduler, pack_prefills
@@ -176,7 +178,10 @@ class Engine:
 
         # jitted cores, hoisted here so admissions never re-jit: the jit
         # cache is keyed by operand shapes, so every same-bucket prefill
-        # and every tick's decode reuse one executable
+        # and every tick's decode reuse one executable.  Parameters and
+        # weight plans are operands, never closure constants: a captured
+        # array is baked into the program as a literal, which at
+        # published widths means gigabytes of HLO.
         self._prefill = jax.jit(self._prefill_impl)
         self._insert = jax.jit(self._insert_impl)
         self._decode = jax.jit(self._decode_impl)
@@ -186,6 +191,12 @@ class Engine:
             self.caches = tfm.init_paged_caches(
                 cfg, self.slots, self.n_pages, self.page, self.cap_pages,
                 quantized=self.quantized)
+            mesh = nn.current_mesh()
+            if mesh is not None:
+                # the pool is shared by every slot: one full copy per
+                # device of the serving mesh, not everything on device 0
+                self.caches = jax.device_put(
+                    self.caches, NamedSharding(mesh, PartitionSpec()))
             self.paged = True
             self.table_host = np.zeros((self.slots, self.n_blocks),
                                        np.int32)
@@ -207,14 +218,14 @@ class Engine:
     # one fused pass over logits the step already materialised — far
     # cheaper than the argmax — so the guard is always on.
 
-    def _prefill_impl(self, tokens, true_len, caches):
+    def _prefill_impl(self, params, plans, tokens, true_len, caches):
         """Batched bucket prefill; logits gathered at each true length."""
         self.prefill_traces += 1
         s = tokens.shape[1]
-        out = tfm.forward(self.params, {"tokens": tokens}, self.cfg,
+        out = tfm.forward(params, {"tokens": tokens}, self.cfg,
                           mode="prefill", caches=caches,
                           positions=jnp.arange(s, dtype=jnp.int32),
-                          rc=self.rc, weight_plans=self.weight_plans)
+                          rc=self.rc, weight_plans=plans)
         idx = jnp.clip(true_len - 1, 0, s - 1)
         logits = jnp.take_along_axis(out.logits, idx[:, None, None],
                                      axis=1)[:, 0]
@@ -241,7 +252,7 @@ class Engine:
             new[posk] = nc
         return new
 
-    def _decode_impl(self, toks, pos, caches, poison):
+    def _decode_impl(self, params, plans, toks, pos, caches, poison):
         """One batched decode step over every serving slot.
 
         ``poison`` NaNs the logits of flagged rows *inside* the trace
@@ -254,10 +265,10 @@ class Engine:
         fault-free runs (DESIGN.md §17).
         """
         self.decode_traces += 1
-        out = tfm.forward(self.params, {"tokens": toks[:, None]},
+        out = tfm.forward(params, {"tokens": toks[:, None]},
                           self.cfg, mode="decode", caches=caches,
                           positions=pos[:, None], rc=self.rc,
-                          weight_plans=self.weight_plans)
+                          weight_plans=plans)
         logits = out.logits[:, -1]
         if poison is not None:
             logits = jnp.where(poison[:, None], jnp.float32(jnp.nan),
@@ -266,11 +277,11 @@ class Engine:
         ok = jnp.all(jnp.isfinite(logits), axis=-1)
         return out.caches, nxt, ok
 
-    def _decode_one_impl(self, tok, pos, caches):
-        out = tfm.forward(self.params, {"tokens": tok[None, None]},
+    def _decode_one_impl(self, params, plans, tok, pos, caches):
+        out = tfm.forward(params, {"tokens": tok[None, None]},
                           self.cfg, mode="decode", caches=caches,
                           positions=pos[None], rc=self.rc,
-                          weight_plans=self.weight_plans)
+                          weight_plans=plans)
         logits = out.logits[0, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         ok = jnp.all(jnp.isfinite(logits))
@@ -542,8 +553,11 @@ class Engine:
         for c in self.caches.values():
             if "kv" in c:
                 kv = c["kv"]
-                c["kv"] = kv._replace(
-                    table=jnp.broadcast_to(tbl[None], kv.table.shape))
+                # keep the leaf's placement: a table with another
+                # sharding would re-trace the decode step
+                c["kv"] = kv._replace(table=jax.device_put(
+                    jnp.broadcast_to(tbl[None], kv.table.shape),
+                    kv.table.sharding))
         self._table_dirty = False
 
     def _retire(self, slot: int) -> None:
@@ -745,8 +759,9 @@ class Engine:
             pre = tfm.init_caches(self.cfg, n, lpad, sparse=False,
                                   full_history=True,
                                   quantized=self.quantized)
-            pre, nxt, ok = self._prefill(jnp.asarray(toks),
-                                         jnp.asarray(lens), pre)
+            pre, nxt, ok = self._prefill(
+                self.params, self.weight_plans, jnp.asarray(toks),
+                jnp.asarray(lens), pre)
             self.prefill_calls += 1
             nxt = np.asarray(nxt)
             ok = np.asarray(ok)
@@ -805,8 +820,8 @@ class Engine:
                 self.caches[i] = tfm.init_caches(
                     self.cfg, 1, self.capacity, quantized=self.quantized)
                 caches, nxt, ok = self._prefill(
-                    toks, jnp.asarray([len(prompt)], jnp.int32),
-                    self.caches[i])
+                    self.params, self.weight_plans, toks,
+                    jnp.asarray([len(prompt)], jnp.int32), self.caches[i])
                 self.prefill_calls += 1
                 self.caches[i] = caches
                 if not bool(np.asarray(ok)[0]):
@@ -865,7 +880,7 @@ class Engine:
         else:
             poison = np.zeros(self.slots, bool)
         self.caches, nxt, ok = self._decode(
-            jnp.asarray(self.last_tok),
+            self.params, self.weight_plans, jnp.asarray(self.last_tok),
             jnp.asarray(self.pos, jnp.int32), self.caches,
             jnp.asarray(poison))
         self.decode_calls += 1
@@ -902,6 +917,7 @@ class Engine:
             if req is None:
                 continue
             caches, nxt, ok = self._decode_one(
+                self.params, self.weight_plans,
                 jnp.asarray(self.last_tok[i], jnp.int32),
                 jnp.asarray(self.pos[i], jnp.int32), self.caches[i])
             self.caches[i] = caches

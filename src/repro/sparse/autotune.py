@@ -42,6 +42,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
+from repro.kernels.platform import resolve_interpret
 from repro.launch import costmodel, roofline
 from repro.sparse import plan as pln
 
@@ -489,7 +490,7 @@ def tune_matmul(x, w, *, mode: str = "dual",
     for d in xv.shape[:-1]:
         m *= d
     n = w_arr.shape[-1]
-    interp = dsp._auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     dt = jax.numpy.dtype(xv.dtype)
     if baseline is None:
         baseline = Knobs("kernel", 128, 128, pln.SLICE_K)
@@ -538,7 +539,7 @@ def tune_grouped(x, w, *, mode: str = "dual",
     w_arr = w.w if hasattr(w, "w") else w
     e, c, k = xv.shape
     n = w_arr.shape[-1]
-    interp = dsp._auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     dt = jax.numpy.dtype(xv.dtype)
     extra = f"e{bucket_dim(e)}"
     if baseline is None:
@@ -640,7 +641,7 @@ def tune_attn(cfg, *, batch: int = 1, capacity: int = 64,
 
     from repro.sparse import dispatch as dsp
     from repro.sparse import kvcache as skvc
-    interp = dsp._auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     mode = cfg.sparse_mode if cfg.sparse_mode != "dense" else "dual"
     fill = capacity // 2 if fill is None else fill
     fill = min(max(int(fill), 1), capacity)

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from repro.core import bitmap as bm
 from repro.core import im2col as i2c
 from repro.core import stats
+from repro.kernels.platform import resolve_interpret
 from repro.sparse import dispatch as dsp
 from repro.sparse import plan as pln
 from repro.sparse import tape
@@ -266,7 +267,7 @@ def conv2d(
         if collect_stats or tape.active():
             # the GEMM-equivalent dense schedule, mirroring matmul's
             # dense branch so conv and LM entries are summable
-            interp = dsp._auto_interpret(interpret)
+            interp = resolve_interpret(interpret)
             bm_, bn_, sk_ = pln.clamp_geometry(
                 n_im * p, f, kkc, block_m, block_n, slice_k, interp)
             dense = jnp.asarray(

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import stats
+from repro.kernels.platform import resolve_interpret
 from repro.sparse import plan as pln
 from repro.sparse import tape
 from repro.sparse import validate
@@ -155,10 +156,30 @@ def _consult_autotune(op: str, m: int, n: int, k: int, dtype,
     return kn
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() == "cpu"
-    return bool(interpret)
+def _kfused_fits(condense, use_kernel: bool, interp: bool, m: int, n: int,
+                 k: int, block_m: int, block_n: int, slice_k: int,
+                 dtype) -> Optional[str]:
+    """``condense`` unless compiled kfused panels cannot fit VMEM.
+
+    The kfused kernels keep full-K operand panels resident
+    (:func:`repro.sparse.plan.kfused_panel_bytes`); where a call's
+    geometry needs more than :data:`repro.sparse.plan.VMEM_BYTES` the
+    slice-granular kernel runs instead (audibly, once per geometry).
+    Interpret mode has no VMEM and keeps the requested schedule.
+    """
+    if condense != "k" or not use_kernel or interp:
+        return condense
+    need = pln.kfused_panel_bytes(block_m, block_n, k, slice_k,
+                                  jnp.dtype(dtype).itemsize)
+    if need <= pln.VMEM_BYTES:
+        return condense
+    warn_once(
+        f"kfused:vmem:{m}x{n}x{k}:{block_m}:{block_n}:{slice_k}",
+        f"sparse dispatch: kfused panels for ({m}, {n}, {k}) at "
+        f"({block_m}, {block_n}, {slice_k}) need {need / 2 ** 20:.1f} MiB "
+        f"of VMEM > {pln.VMEM_BYTES / 2 ** 20:.0f} MiB; running the "
+        "slice-granular kernel for this geometry")
+    return None
 
 
 def _values(x: Operand) -> jax.Array:
@@ -294,7 +315,7 @@ def matmul(
     n = w_arr.shape[1]
     w_arr = w_arr.astype(xv.dtype)
 
-    interp = _auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     if autotune and mode != "dense":
         kn = _consult_autotune(op, t, n, k, x2.dtype,
                                tune_sparsity, interp)
@@ -307,6 +328,9 @@ def matmul(
             condense = tuned["condense"]
     block_m, block_n, slice_k = pln.clamp_geometry(
         t, n, k, block_m, block_n, slice_k, interp)
+    if mode != "dense":
+        condense = _kfused_fits(condense, use_kernel, interp, t, n, k,
+                                block_m, block_n, slice_k, x2.dtype)
     mt, nt, s = (pln._cdiv(t, block_m), pln._cdiv(n, block_n),
                  pln._cdiv(k, slice_k))
 
@@ -479,7 +503,7 @@ def grouped_matmul(
     n = w_arr.shape[-1]
     w_arr = w_arr.astype(xv.dtype)
 
-    interp = _auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     if autotune and mode != "dense":
         from repro.sparse import autotune as atn
         kn = _consult_autotune("grouped", c, n, k, xv.dtype,
@@ -494,6 +518,9 @@ def grouped_matmul(
             condense = tuned["condense"]
     block_m, block_n, slice_k = pln.clamp_geometry(
         c, n, k, block_m, block_n, slice_k, interp)
+    if mode != "dense":
+        condense = _kfused_fits(condense, use_kernel, interp, c, n, k,
+                                block_m, block_n, slice_k, xv.dtype)
     s = pln._cdiv(k, slice_k)
 
     def _xla_grouped():

@@ -523,12 +523,16 @@ def effective_slice_k(k: int, slice_k: int = SLICE_K) -> int:
 # knob validity (autotuner contract, DESIGN.md §13)
 # ---------------------------------------------------------------------------
 
-# Per-core VMEM budget the kfused kernels' resident panels must fit in
-# (TPU v5e has ~16 MiB/core; leave headroom for the grid machinery).
-VMEM_BYTES = 16 * 2 ** 20
+# Scoped VMEM every kernel requests (``CompilerParams.vmem_limit_bytes``)
+# and the budget :func:`knobs_valid` holds a tile to.  Mosaic's default
+# scoped limit on v5e is 16 MiB; the chips listed in
+# ``repro.kernels.platform.VMEM_CAPACITY`` have 128 MiB per core, and
+# the rest is left to Mosaic's own scratch.
+VMEM_BYTES = 96 * 2 ** 20
 SUBLANE = 8     # second-minor tile unit
 LANE = 128      # minor (lane) tile unit
 F32_BYTES = 4   # accumulator scratch dtype
+BUFFERS = 2     # the Pallas pipeline double-buffers every blocked operand
 
 
 def _round_up(x: int, unit: int) -> int:
@@ -537,25 +541,29 @@ def _round_up(x: int, unit: int) -> int:
 
 def kfused_panel_bytes(block_m: int, block_n: int, k: int, slice_k: int,
                        dtype_bytes: int = 4) -> int:
-    """Resident-panel footprint of the kfused kernels for one grid step.
+    """VMEM footprint of the kfused kernels.
 
     ``bitmap_spgemm_kfused_planned`` keeps the full-K operand panels
-    VMEM-resident so the packed-k gathers never leave the core:
-    a (block_m, Kp) A panel + a (Kp, block_n) B panel at the compute
-    dtype, plus the (block_m, block_n) f32 accumulator scratch, where
-    Kp = ceil(K / slice_k) · slice_k.
+    VMEM-resident so the packed-k gathers never leave the core: a
+    (block_m, Kp) A panel, a (Kp, block_n) B panel and the block's
+    (S, slice_k) int32 schedule, each double-buffered, plus the
+    (block_m, block_n) output block (double-buffered, counted at f32)
+    and f32 accumulator, where Kp = S · slice_k = ceil(K / slice_k) ·
+    slice_k.
     """
     kp = _cdiv(max(k, 1), slice_k) * slice_k
-    return ((block_m * kp + kp * block_n) * dtype_bytes
+    return (BUFFERS * ((block_m * kp + kp * block_n) * dtype_bytes
+                       + kp * 4 + block_m * block_n * F32_BYTES)
             + block_m * block_n * F32_BYTES)
 
 
 def slice_panel_bytes(block_m: int, block_n: int, slice_k: int,
                       dtype_bytes: int = 4) -> int:
-    """Resident footprint of the slice-granular kernel for one grid step:
-    one (block_m, slice_k) A block + (slice_k, block_n) B block + the
-    f32 accumulator."""
-    return ((block_m * slice_k + slice_k * block_n) * dtype_bytes
+    """VMEM footprint of the slice-granular kernel: a (block_m, slice_k)
+    A block, a (slice_k, block_n) B block and the output block, each
+    double-buffered, plus the f32 accumulator."""
+    return (BUFFERS * ((block_m * slice_k + slice_k * block_n) * dtype_bytes
+                       + block_m * block_n * F32_BYTES)
             + block_m * block_n * F32_BYTES)
 
 
@@ -572,7 +580,9 @@ def knobs_valid(m: int, n: int, k: int, block_m: int, block_n: int,
 
     * tile divisibility — block_m a multiple of the 8-sublane unit,
       block_n a multiple of the 128-lane unit (8 under interpret, where
-      lanes are emulated), slice_k a multiple of 8;
+      lanes are emulated), slice_k a multiple of 8 and, compiled, of
+      the 128-lane unit unless one slice spans all of K (the slice is
+      the minor dimension of the A block);
     * no over-tiling — each knob at most the problem dimension rounded
       up to its tile unit (``clamp_geometry`` would silently shrink
       anything larger, so the served vector would not be the one that
@@ -587,6 +597,8 @@ def knobs_valid(m: int, n: int, k: int, block_m: int, block_n: int,
         return False
     lane = SUBLANE if interpret else LANE
     if block_m % SUBLANE or block_n % lane or slice_k % SUBLANE:
+        return False
+    if not interpret and slice_k % LANE and slice_k < k:
         return False
     if block_m > _round_up(m, SUBLANE) or block_n > _round_up(n, lane):
         return False
@@ -607,9 +619,15 @@ def clamp_geometry(m: int, n: int, k: int, block_m: int, block_n: int,
                    slice_k: int, interpret: bool) -> Tuple[int, int, int]:
     """Clamp block sizes for small problems, keeping lane alignment.
 
-    Mirrors the clamping inside ``bitmap_spgemm`` so externally built
-    plans agree with the kernel's grid.
+    The one geometry every kernel entry point and every externally built
+    plan uses, so plans agree with the kernel's grid.  Compiled kernels
+    also need the slice — the minor dimension of the A block — to be a
+    lane multiple or all of K: a narrower slice (the KV decode's 32-slot
+    value tile) widens to the next lane multiple.
     """
     block_m = min(block_m, max(8, m))
-    block_n = min(block_n, max(8 if interpret else 128, n))
-    return block_m, block_n, effective_slice_k(k, slice_k)
+    block_n = min(block_n, max(8 if interpret else LANE, n))
+    slice_k = effective_slice_k(k, slice_k)
+    if not interpret and slice_k < k and slice_k % LANE:
+        slice_k = min(_round_up(slice_k, LANE), k)
+    return block_m, block_n, slice_k

@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import im2col as i2c
+from repro.kernels.platform import resolve_interpret
 from repro.sparse import conv as scv
 from repro.sparse import dispatch as dsp
 from repro.sparse.activation import SparseActivation
@@ -216,7 +217,7 @@ def resolve(st: OpSite, cfg, *, m: int, n: int, k: int, e: int = 1,
     kw = _base_kwargs(st, cfg)
     if cfg.sparse_mode == "dense":
         return _degrade(st, kw)
-    interp = dsp._auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     dt = jnp.dtype(st.dtype) if st.dtype else jnp.dtype(dtype)
     hint = st.sparsity if st.sparsity >= 0 else float(
         getattr(cfg, "sparse_tune_sparsity", -1.0))

@@ -88,3 +88,47 @@ def test_collective_parser():
                                ("all-gather", "all-reduce",
                                 "reduce-scatter", "all-to-all",
                                 "collective-permute"))
+
+
+def test_kernel_platform_check_refuses_cpu():
+    """Off the TPU the kernels interpret, and the chip smoke's device
+    check fails instead of carrying on."""
+    from repro.kernels import platform
+    assert platform.resolve_interpret(None) is True
+    assert platform.resolve_interpret(False) is False
+    with pytest.raises(RuntimeError, match="no TPU"):
+        platform.check_tpu()
+
+
+def test_tpu_latency_flags_append_to_libtpu_init_args():
+    from repro.launch import flags
+    env = {"LIBTPU_INIT_ARGS": "--machine_flag=1", "XLA_FLAGS": "--x=1"}
+    merged = flags.apply_latency_flags("tpu", env=env)
+    assert merged.split()[0] == "--machine_flag=1"
+    assert env["XLA_FLAGS"] == "--x=1"
+    assert set(merged.split()[1:]) == set(flags.LATENCY_FLAGS["tpu"])
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    from repro.launch import serve
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        path = serve.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+    assert path == (str(tmp_path) if env_dir
+                    else str(serve.REPO_ROOT / ".jax_cache"))
+
+
+def test_host_mesh_spans_present_devices_with_auto_axes():
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    assert mesh.devices.size == len(jax.devices())
+    assert all(t == AxisType.Auto for t in mesh.axis_types)

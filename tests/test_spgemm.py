@@ -139,3 +139,28 @@ def test_spgemm_wrapper_stats(rng):
     np.testing.assert_allclose(np.asarray(res.out), a @ b, rtol=1e-4,
                                atol=1e-4)
     assert int(res.steps.dense) >= int(res.steps.sparse) > 0
+
+
+@pytest.mark.parametrize("kernel", ["slice", "kfused", "grouped",
+                                    "grouped_kfused"])
+def test_kernels_split_schedules_that_overflow_smem(rng, monkeypatch,
+                                                    kernel):
+    """A schedule too big for one call's SMEM runs as several calls over
+    sub-rectangles of the output grid (or expert ranges) — same result."""
+    from repro.kernels import bitmap_spgemm as bsk
+    from repro.kernels import grouped_spgemm as gsk
+    monkeypatch.setattr(bsk, "SMEM_SCHEDULE_WORDS", 16)
+    a = sparse_matrix(rng, (40, 300), 0.5)
+    b = sparse_matrix(rng, (300, 50), 0.5)
+    kw = dict(block_m=8, block_n=16, slice_k=32, interpret=True)
+    if kernel.startswith("grouped"):
+        a, b = np.stack([a[:, :64]] * 3), np.stack([b[:64]] * 3)
+        fn = (gsk.grouped_spgemm if kernel == "grouped"
+              else gsk.grouped_spgemm_kfused)
+        want = np.einsum("eck,ekn->ecn", a, b)
+    else:
+        fn = (bsk.bitmap_spgemm if kernel == "slice"
+              else bsk.bitmap_spgemm_kfused)
+        want = a @ b
+    out = fn(jnp.asarray(a), jnp.asarray(b), **kw)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
