@@ -1,22 +1,19 @@
-"""Continuous-batching engine benchmark: throughput vs slot count.
+"""Continuous-batching engine checks: compile evidence vs slot count.
 
 A fixed workload of requests with mixed prompt lengths runs through the
 paged ``repro.serving.engine.Engine`` at increasing slot counts.  Each
-configuration does one untimed warmup wave (compiles the bucketed
-prefill, the insert scatter, and the single batched decode step) and
-then a timed wave on the same engine, so the steady-state numbers
-measure dispatch + execution, not tracing.
+configuration does one warmup wave (compiles the bucketed prefill, the
+insert scatter, and the single batched decode step) and then a second
+wave on the same engine.  Times are the chip benchmark's
+(``chipbench/``); on the CPU this asserts and emits counts only.
 
-Per configuration we emit
-
-* ``serving.tick.slots{N}`` — median-free wall time per engine tick
-  (one tick == exactly one jitted batched decode call spanning all
-  active slots), with derived tokens/s over the timed wave, and
-* the compile evidence from ``Engine.stats()``: ``decode_traces`` must
-  stay 1 per engine regardless of slot count (the decode step is traced
-  once for the ``(slots,)`` batch and reused every tick) and
-  ``prefill_traces`` stays at the number of distinct bucket geometries,
-  not the number of admissions.  The timed wave must add zero traces.
+Per configuration we emit ``serving.slots{N}.{mode}`` with the compile
+evidence from ``Engine.stats()``: ``decode_traces`` must stay 1 per
+engine regardless of slot count (the decode step is traced once for the
+``(slots,)`` batch and reused every tick) and ``prefill_traces`` stays
+at the number of distinct bucket geometries, not the number of
+admissions.  The second wave must add zero traces and run one batched
+decode call per tick.
 
 ``--sparse`` routes decode through the bitmap-scheduled sparse KV path
 (grouped_matmul with one E=B*KV grid spanning slots) instead of dense
@@ -31,7 +28,6 @@ contract is asserted on both arms and the tuned arm adds zero traces.
 """
 import argparse
 import dataclasses
-import time
 
 import jax
 import numpy as np
@@ -55,14 +51,12 @@ def _workload(n_req: int, lens, vocab: int, max_new: int, uid0: int = 0):
     return reqs
 
 
-def _drive(eng: Engine, reqs) -> float:
-    """Submit + run to completion; return elapsed wall seconds."""
-    t0 = time.perf_counter()
+def _drive(eng: Engine, reqs) -> None:
+    """Submit + run to completion."""
     for r in reqs:
         eng.submit(r)
     done = eng.run_to_completion()
     assert len(done) == len(reqs)
-    return time.perf_counter() - t0
 
 
 def run(smoke: bool = False, sparse: bool = False) -> None:
@@ -86,10 +80,10 @@ def run(smoke: bool = False, sparse: bool = False) -> None:
         # warmup wave: compiles prefill (per bucket), insert, decode
         _drive(eng, _workload(n_req, lens, cfg.vocab_size, max_new))
         warm = eng.stats()
-        # timed wave on the same engine: must hit the jit caches only
+        # second wave on the same engine: must hit the jit caches only
         reqs = _workload(n_req, lens, cfg.vocab_size, max_new,
                          uid0=n_req)
-        dt = _drive(eng, reqs)
+        _drive(eng, reqs)
         st = eng.stats()
         new_traces = (st["prefill_traces"] - warm["prefill_traces"]
                       + st["decode_traces"] - warm["decode_traces"])
@@ -99,16 +93,15 @@ def run(smoke: bool = False, sparse: bool = False) -> None:
         decode_calls = st["decode_calls"] - warm["decode_calls"]
         assert decode_calls <= ticks      # one batched decode per tick
         toks = sum(len(r.output) for r in reqs)
-        emit(f"serving.tick.slots{slots}.{mode}",
-             dt / max(ticks, 1) * 1e6,
-             f"tok_s={toks / dt:.1f};ticks={ticks};"
+        emit(f"serving.slots{slots}.{mode}", None,
+             f"tokens={toks};ticks={ticks};"
              f"decode_calls={decode_calls};"
              f"decode_traces={st['decode_traces']};"
              f"prefill_traces={st['prefill_traces']};"
              f"evictions={st['evictions']};"
              f"pages_free={st['pages_free']};"
              f"pages_total={st['pages_total']}")
-    print(f"# OK [{mode}]: decode traced once per engine, timed wave "
+    print(f"# OK [{mode}]: decode traced once per engine, second wave "
           "added zero traces, one batched decode call per tick")
 
 
@@ -120,7 +113,7 @@ def run_tune(smoke: bool = False) -> dict:
     drives two engines over the same workload: one on the hand-set
     config constants, one with ``sparse_autotune`` consuming the tuned
     ``attn.score``/``attn.value`` knobs at trace time.  Both must keep
-    ``decode_traces == 1`` with a zero-trace timed wave (the PR 7
+    ``decode_traces == 1`` with a zero-trace second wave (the PR 7
     contract: tuned knobs are jit-constants, never extra traces).
     """
     from repro.sparse import autotune as atn
@@ -146,7 +139,6 @@ def run_tune(smoke: bool = False) -> dict:
 
     print(f"# bench_serving [tune]: slots={slots} capacity={capacity}, "
           f"attn sites swept at t={cap_pages} E={slots * cfg.n_kv_heads}")
-    tick_us = {}
     hits0 = atn.HITS
     for arm, c in (("untuned", cfg),
                    ("tuned",
@@ -158,23 +150,19 @@ def run_tune(smoke: bool = False) -> dict:
             warm = eng.stats()
             reqs = _workload(n_req, lens, cfg.vocab_size, max_new,
                              uid0=n_req)
-            dt = _drive(eng, reqs)
+            _drive(eng, reqs)
         st = eng.stats()
-        # one decode trace per engine, tuned included; timed wave adds 0
+        # one decode trace per engine, tuned included; second wave adds 0
         assert st["decode_traces"] == 1, st
         assert st["decode_traces"] == warm["decode_traces"], (warm, st)
         ticks = st["ticks"] - warm["ticks"]
-        tick_us[arm] = dt / max(ticks, 1) * 1e6
-        emit(f"serving.tick.tune.{arm}", tick_us[arm],
+        emit(f"serving.tune.{arm}", None,
              f"ticks={ticks};decode_traces={st['decode_traces']}")
     assert atn.HITS > hits0, \
         "tuned decode was not served from the attention sites"
     print(f"# OK [tune]: tuned decode served {atn.HITS - hits0} cache "
-          "hit(s) in one decode trace; step latency "
-          f"untuned={tick_us['untuned']:.1f}us "
-          f"tuned={tick_us['tuned']:.1f}us")
-    return {"attn_sweep": rows, "tick_us": tick_us,
-            "hits": atn.HITS - hits0}
+          "hit(s) in one decode trace")
+    return {"attn_sweep": rows, "hits": atn.HITS - hits0}
 
 
 def run_chaos(smoke: bool = False, seed: int = 0) -> dict:
@@ -248,7 +236,6 @@ def run_chaos(smoke: bool = False, seed: int = 0) -> dict:
             _w.simplefilter("ignore")
             atn.load_cache(cache_path)      # degrades to empty, no raise
         assert atn.get_cache().entries == {}
-        t0 = time.perf_counter()
         with faults.chaos(seed=seed, alloc_rate=0.25, storm_rate=0.2,
                           poisoned_uids=poisoned):
             # engine built INSIDE the fault context: the nan_logits
@@ -261,7 +248,6 @@ def run_chaos(smoke: bool = False, seed: int = 0) -> dict:
                 eng.submit(r)
             done = {r.uid: r for r in eng.run_to_completion()}
             eng.validate_state()            # invariants clean at exit
-        dt = time.perf_counter() - t0
     os.unlink(cache_path)
 
     assert sorted(done) == sorted(ref), (sorted(done), sorted(ref))
@@ -281,7 +267,7 @@ def run_chaos(smoke: bool = False, seed: int = 0) -> dict:
     assert quarantines, "kernel faults never hit a site"
     assert st["errored"] == len(poisoned), st
 
-    emit("serving.chaos.wall_s", dt,
+    emit("serving.chaos", None,
          f"requests={n_req};errored={st['errored']};"
          f"evictions={st['evictions']};ticks={st['ticks']};"
          f"decode_traces={st['decode_traces']};"

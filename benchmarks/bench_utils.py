@@ -61,9 +61,14 @@ def _parse_derived(derived: str) -> dict:
     return out
 
 
-def emit(name: str, us: float, derived: str = "") -> None:
-    print(f"{name},{us:.1f},{derived}", flush=True)
-    entry = {"name": name, "us_per_call": round(us, 1)}
+def emit(name: str, us: Optional[float], derived: str = "") -> None:
+    """One record; ``us`` None for a record that times nothing (an empty
+    CSV field, no ``us_per_call`` key)."""
+    print(f"{name},{'' if us is None else f'{us:.1f}'},{derived}",
+          flush=True)
+    entry = {"name": name}
+    if us is not None:
+        entry["us_per_call"] = round(us, 1)
     if derived:
         entry.update(_parse_derived(derived))
     RESULTS.append(entry)

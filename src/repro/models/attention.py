@@ -212,41 +212,46 @@ def attend_sparse(q: jax.Array, cache, cfg: ModelConfig, *,
     g = h // kvh
     ne = b * kvh
 
-    # dequantise / cast exactly like the dense decode branches; paged
-    # caches gather their logical per-slot view first (DESIGN.md §14)
-    paged = isinstance(cache, skvc.PagedSparseKVCache)
-    if paged:
-        kd, vd = skvc.paged_read(cache, dtype=q.dtype)
-        occ = skvc.paged_occupancy_mask(cache)          # (B, T)
-    elif cache.quantized:
-        kd = (cache.k.astype(jnp.bfloat16)
-              * cache.k_scale.astype(jnp.bfloat16)).astype(q.dtype)
-        vd = (cache.v.astype(jnp.bfloat16)
-              * cache.v_scale.astype(jnp.bfloat16)).astype(q.dtype)
-        occ = skvc.occupancy_mask(cache)                # (T,)
-    else:
-        kd, vd, _ = kvc.read(cache, dtype=q.dtype)
-        occ = skvc.occupancy_mask(cache)
-    kd_e = kd.transpose(0, 2, 1, 3).reshape(ne, t, hd)
-    vd_e = vd.transpose(0, 2, 1, 3).reshape(ne, t, hd)
-    qw = q.reshape(b, kvh, g, hd).transpose(0, 1, 3, 2).reshape(ne, hd, g)
+    # the cache gather and the operand build run under the ``attn.kv``
+    # scope: device time of the paged view, apart from the two matmuls
+    with jax.named_scope("attn.kv"):
+        # dequantise / cast exactly like the dense decode branches; paged
+        # caches gather their logical per-slot view first (DESIGN.md §14)
+        paged = isinstance(cache, skvc.PagedSparseKVCache)
+        if paged:
+            kd, vd = skvc.paged_read(cache, dtype=q.dtype)
+            occ = skvc.paged_occupancy_mask(cache)          # (B, T)
+        elif cache.quantized:
+            kd = (cache.k.astype(jnp.bfloat16)
+                  * cache.k_scale.astype(jnp.bfloat16)).astype(q.dtype)
+            vd = (cache.v.astype(jnp.bfloat16)
+                  * cache.v_scale.astype(jnp.bfloat16)).astype(q.dtype)
+            occ = skvc.occupancy_mask(cache)                # (T,)
+        else:
+            kd, vd, _ = kvc.read(cache, dtype=q.dtype)
+            occ = skvc.occupancy_mask(cache)
+        kd_e = kd.transpose(0, 2, 1, 3).reshape(ne, t, hd)
+        vd_e = vd.transpose(0, 2, 1, 3).reshape(ne, t, hd)
+        qw = q.reshape(b, kvh, g, hd).transpose(0, 1, 3, 2).reshape(
+            ne, hd, g)
 
-    # the decode plan: maintained occupancy AND the causal/window mask.
-    # Occupancy ≡ kpos >= 0 (property-tested), so ``sched`` doubles as
-    # the dense path's softmax validity mask bit-for-bit; the dispatch
-    # layer derives the block-level front-pack from the operand metadata.
-    # Paged multi-slot decode carries per-row positions: qpos (B, 1) and
-    # kpos (B, T) yield a per-slot (B, T) schedule, expanded over the kv
-    # heads of each slot to per-problem (E, T) metadata.
-    qref = qpos[0] if qpos.ndim == 1 else qpos
-    sched = pln.kv_decode_slots(occ, kpos, qref, window)
-    if sched.ndim == 2:
-        sched_e = jnp.broadcast_to(
-            sched[:, None, :], (b, kvh, t)).reshape(ne, t)
-        occ_e = jnp.broadcast_to(
-            occ[:, None, :], (b, kvh, t)).reshape(ne, t)
-    else:
-        sched_e, occ_e = sched, occ
+        # the decode plan: maintained occupancy AND the causal/window
+        # mask.  Occupancy ≡ kpos >= 0 (property-tested), so ``sched``
+        # doubles as the dense path's softmax validity mask bit-for-bit;
+        # the dispatch layer derives the block-level front-pack from the
+        # operand metadata.  Paged multi-slot decode carries per-row
+        # positions: qpos (B, 1) and kpos (B, T) yield a per-slot (B, T)
+        # schedule, expanded over the kv heads of each slot to
+        # per-problem (E, T) metadata.
+        qref = qpos[0] if qpos.ndim == 1 else qpos
+        sched = pln.kv_decode_slots(occ, kpos, qref, window)
+        if sched.ndim == 2:
+            sched_e = jnp.broadcast_to(
+                sched[:, None, :], (b, kvh, t)).reshape(ne, t)
+            occ_e = jnp.broadcast_to(
+                occ[:, None, :], (b, kvh, t)).reshape(ne, t)
+        else:
+            sched_e, occ_e = sched, occ
     # first-class decode tuning sites (DESIGN.md §16): attn.score keys on
     # (M=T, N=G, K=hd) — slots are block rows, so the served block_m IS
     # the slot tile — and attn.value on (M=G, N=hd, K=T) — slots are the
@@ -267,7 +272,8 @@ def attend_sparse(q: jax.Array, cache, cfg: ModelConfig, *,
     sk_hd = pln.clamp_geometry(t, g, hd, kw_s["block_m"], kw_s["block_n"],
                                kw_s["slice_k"], interp)[2]
 
-    x_k = skvc.score_operand(kd_e, sched_e, sk_hd)
+    with jax.named_scope("attn.kv"):
+        x_k = skvc.score_operand(kd_e, sched_e, sk_hd)
     scores_t, _ = sp.site.grouped_matmul(x_k, qw, st_s, cfg,
                                          resolved=kw_s)
     scores = scores_t.reshape(b, kvh, t, g).transpose(0, 1, 3, 2)
@@ -282,7 +288,8 @@ def attend_sparse(q: jax.Array, cache, cfg: ModelConfig, *,
     l = jnp.sum(e, axis=-1)                            # (B,KV,G,1)
 
     p_e = e[:, :, :, 0, :].reshape(ne, g, t)
-    x_p, w_v = skvc.value_operands(occ_e, p_e, vd_e, sched_e, bt)
+    with jax.named_scope("attn.kv"):
+        x_p, w_v = skvc.value_operands(occ_e, p_e, vd_e, sched_e, bt)
     acc_e, _ = sp.site.grouped_matmul(x_p, w_v, st_v, cfg,
                                       resolved={**kw_v, "slice_k": bt})
 

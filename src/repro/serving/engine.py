@@ -34,17 +34,31 @@ instead of crashing admission; ``run_to_completion`` watches for
 progress and raises :class:`EngineStalled` carrying an
 :meth:`Engine.health` snapshot plus the unfinished requests rather than
 silently dropping in-flight work.
+
+Tracing: each tick and its phases open ``engine.*`` host spans
+(``jax.profiler.TraceAnnotation``: ``engine.step`` around ``admit``,
+``prefill``, ``insert``, ``ensure_pages``, ``push_table``, ``decode``,
+``sync``, ``retire`` and ``validate``), and each jitted core opens
+``engine.trace.<core>`` while it is being traced, so a recompile shows
+on the profiler's timeline.  The spans cost about a microsecond each
+while no profiler runs.  Requests carry two wall-clock stamps on
+``time.perf_counter()``, the clock of the benchmark harness, which reads
+them: ``t_prefill`` (start of the request's own first prefill call) and
+``t_first`` (its first token on the host).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import sys
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import sparse
@@ -79,6 +93,11 @@ class Request:
     submit_tick: int = dataclasses.field(default=0, repr=False)
     not_before: int = dataclasses.field(default=0, repr=False)
     preempt_retries: int = dataclasses.field(default=0, repr=False)
+    # wall-clock stamps (``time.perf_counter()``): start of its own first
+    # prefill call, first token on the host
+    t_prefill: Optional[float] = dataclasses.field(default=None,
+                                                   repr=False)
+    t_first: Optional[float] = dataclasses.field(default=None, repr=False)
 
 
 class EngineStalled(RuntimeError):
@@ -98,6 +117,19 @@ class EngineStalled(RuntimeError):
 
 def _round_up(x: int, unit: int) -> int:
     return -(-x // unit) * unit
+
+
+def _traced(impl):
+    """``impl`` inside an ``engine.trace.<core>`` span (``_decode_impl`` →
+    ``engine.trace.decode``).  Jitted, the span opens only while the
+    program is being traced: a recompile shows on the timeline."""
+    name = "engine.trace." + impl.__name__[1:-len("_impl")]
+
+    @functools.wraps(impl)
+    def traced(*args):
+        with TraceAnnotation(name):
+            return impl(*args)
+    return traced
 
 
 class Engine:
@@ -152,6 +184,8 @@ class Engine:
         self.evictions = 0
         self.prefill_traces = 0
         self.prefill_calls = 0
+        self.prefill_tokens_true = 0     # prompt tokens prefilled
+        self.prefill_tokens_padded = 0   # rows x padded length dispatched
         self.insert_traces = 0
         self.decode_traces = 0
         self.decode_calls = 0
@@ -182,10 +216,10 @@ class Engine:
         # weight plans are operands, never closure constants: a captured
         # array is baked into the program as a literal, which at
         # published widths means gigabytes of HLO.
-        self._prefill = jax.jit(self._prefill_impl)
-        self._insert = jax.jit(self._insert_impl)
-        self._decode = jax.jit(self._decode_impl)
-        self._decode_one = jax.jit(self._decode_one_impl)
+        self._prefill = jax.jit(_traced(self._prefill_impl))
+        self._insert = jax.jit(_traced(self._insert_impl))
+        self._decode = jax.jit(_traced(self._decode_impl))
+        self._decode_one = jax.jit(_traced(self._decode_one_impl))
 
         try:
             self.caches = tfm.init_paged_caches(
@@ -442,12 +476,16 @@ class Engine:
 
     # -- paged control plane ------------------------------------------
     def stats(self) -> Dict[str, int]:
-        """Control-plane counters (compile evidence for bench_serving)."""
+        """Control-plane counters.  Read by the benchmark harness
+        (compile evidence, errored requests, prefill padding) and by the
+        watchdog's stall report (through :meth:`health`)."""
         return {
             "ticks": self.ticks,
             "evictions": self.evictions,
             "prefill_traces": self.prefill_traces,
             "prefill_calls": self.prefill_calls,
+            "prefill_tokens_true": self.prefill_tokens_true,
+            "prefill_tokens_padded": self.prefill_tokens_padded,
             "insert_traces": self.insert_traces,
             "decode_traces": self.decode_traces,
             "decode_calls": self.decode_calls,
@@ -540,7 +578,8 @@ class Engine:
 
     def _maybe_validate(self) -> None:
         if self._validate or sparse.validate.enabled():
-            self.validate_state()
+            with TraceAnnotation("engine.validate"):
+                self.validate_state()
 
     def _prompt_of(self, req: Request) -> List[int]:
         return req.resume_prompt or req.prompt
@@ -617,6 +656,8 @@ class Engine:
     def _append_token(self, req: Request, tok: int) -> None:
         req.output.append(tok)
         self.tokens_emitted += 1
+        if req.t_first is None:
+            req.t_first = time.perf_counter()
 
     def _deadline_blown(self, req: Request) -> bool:
         return (req.deadline_ticks is not None
@@ -726,45 +767,45 @@ class Engine:
         if not self.paged:
             return self._admit_legacy()
         finished: List[Request] = []
-        free_slots = [i for i in range(self.slots)
-                      if self.active[i] is None]
-        admitted: List[Request] = []
-        reserved = 0
-        while len(admitted) < len(free_slots) and len(self.scheduler):
-            req = self.scheduler.pop_next(
-                max_pages=self.allocator.available - reserved,
-                pages_of=self._prefill_pages,
-                now=self.ticks)
-            if req is None:
-                break
-            admitted.append(req)
-            reserved += self._prefill_pages(req)
-        if not admitted:
-            return finished
-
-        groups = pack_prefills(
-            admitted, bucket=self.bucket,
-            max_batch=max(1, self.serve.max_prefill_batch),
-            pack=not self._exact_prefill,
-            length_of=lambda r: len(self._prompt_of(r)))
+        with TraceAnnotation("engine.admit"):
+            free_slots = [i for i in range(self.slots)
+                          if self.active[i] is None]
+            admitted: List[Request] = []
+            reserved = 0
+            while len(admitted) < len(free_slots) and len(self.scheduler):
+                req = self.scheduler.pop_next(
+                    max_pages=self.allocator.available - reserved,
+                    pages_of=self._prefill_pages,
+                    now=self.ticks)
+                if req is None:
+                    break
+                admitted.append(req)
+                reserved += self._prefill_pages(req)
+            if not admitted:
+                return finished
+            groups = pack_prefills(
+                admitted, bucket=self.bucket,
+                max_batch=max(1, self.serve.max_prefill_batch),
+                pack=not self._exact_prefill,
+                length_of=lambda r: len(self._prompt_of(r)))
         for lpad, group in groups:
             lpad = min(max(lpad, 1), self.cap_pages)
             n = len(group)
-            toks = np.zeros((n, lpad), np.int32)
-            lens = np.zeros((n,), np.int32)
-            for r_i, req in enumerate(group):
-                p = self._prompt_of(req)
-                toks[r_i, :len(p)] = p
-                lens[r_i] = len(p)
-            pre = tfm.init_caches(self.cfg, n, lpad, sparse=False,
-                                  full_history=True,
-                                  quantized=self.quantized)
-            pre, nxt, ok = self._prefill(
-                self.params, self.weight_plans, jnp.asarray(toks),
-                jnp.asarray(lens), pre)
-            self.prefill_calls += 1
-            nxt = np.asarray(nxt)
-            ok = np.asarray(ok)
+            lens = np.asarray([len(self._prompt_of(r)) for r in group],
+                              np.int32)
+            with TraceAnnotation("engine.prefill"):
+                self._stamp_prefill(group, int(lens.sum()), n * lpad)
+                toks = np.zeros((n, lpad), np.int32)
+                for r_i, req in enumerate(group):
+                    toks[r_i, :lens[r_i]] = self._prompt_of(req)
+                pre = tfm.init_caches(self.cfg, n, lpad, sparse=False,
+                                      full_history=True,
+                                      quantized=self.quantized)
+                pre, nxt, ok = self._prefill(
+                    self.params, self.weight_plans, jnp.asarray(toks),
+                    jnp.asarray(lens), pre)
+                nxt = np.asarray(nxt)
+                ok = np.asarray(ok)
             for r_i, req in enumerate(group):
                 if not bool(ok[r_i]):
                     # poisoned prompt: its logits went non-finite — the
@@ -796,10 +837,11 @@ class Engine:
                 self.table_host[slot, :] = 0
                 self.table_host[slot, :nbr] = pages
                 self.pages_held[slot] = list(pages)
-                self.caches = self._insert(
-                    self.caches, pre, jnp.int32(r_i), jnp.int32(slot),
-                    jnp.asarray(pages, jnp.int32),
-                    jnp.int32(int(lens[r_i])))
+                with TraceAnnotation("engine.insert"):
+                    self.caches = self._insert(
+                        self.caches, pre, jnp.int32(r_i), jnp.int32(slot),
+                        jnp.asarray(pages, jnp.int32),
+                        jnp.int32(int(lens[r_i])))
                 self.pos[slot] = int(lens[r_i])
                 self.last_tok[slot] = tok
                 self.active[slot] = req
@@ -816,15 +858,19 @@ class Engine:
                 if req is None:
                     break
                 prompt = self._prompt_of(req)
-                toks = jnp.asarray(prompt, jnp.int32)[None]
-                self.caches[i] = tfm.init_caches(
-                    self.cfg, 1, self.capacity, quantized=self.quantized)
-                caches, nxt, ok = self._prefill(
-                    self.params, self.weight_plans, toks,
-                    jnp.asarray([len(prompt)], jnp.int32), self.caches[i])
-                self.prefill_calls += 1
+                with TraceAnnotation("engine.prefill"):
+                    self._stamp_prefill([req], len(prompt), len(prompt))
+                    toks = jnp.asarray(prompt, jnp.int32)[None]
+                    self.caches[i] = tfm.init_caches(
+                        self.cfg, 1, self.capacity,
+                        quantized=self.quantized)
+                    caches, nxt, ok = self._prefill(
+                        self.params, self.weight_plans, toks,
+                        jnp.asarray([len(prompt)], jnp.int32),
+                        self.caches[i])
+                    nxt, ok = np.asarray(nxt), np.asarray(ok)
                 self.caches[i] = caches
-                if not bool(np.asarray(ok)[0]):
+                if not bool(ok[0]):
                     finished.append(
                         self._error_retire(req, "nonfinite_logits"))
                     continue
@@ -842,8 +888,24 @@ class Engine:
                 req.status = "active"
         return finished
 
+    def _stamp_prefill(self, group: List[Request], true_len: int,
+                       padded_len: int) -> None:
+        """Count one prefill call and stamp the start of each request's
+        first prefill."""
+        self.prefill_calls += 1
+        self.prefill_tokens_true += true_len
+        self.prefill_tokens_padded += padded_len
+        now = time.perf_counter()
+        for req in group:
+            if req.t_prefill is None:
+                req.t_prefill = now
+
     def step(self) -> List[Request]:
         """One engine tick: admit, one batched decode, retire."""
+        with TraceAnnotation("engine.step"):
+            return self._tick()
+
+    def _tick(self) -> List[Request]:
         self.ticks += 1
         finished = self._early
         self._early = []
@@ -859,12 +921,14 @@ class Engine:
         if all(r is None for r in self.active.values()):
             self._maybe_validate()
             return finished
-        self._ensure_pages()
+        with TraceAnnotation("engine.ensure_pages"):
+            self._ensure_pages()
         if all(r is None for r in self.active.values()):
             self._maybe_validate()
             return finished
         if self._table_dirty:
-            self._push_table()
+            with TraceAnnotation("engine.push_table"):
+                self._push_table()
         # The poison mask is ALWAYS passed (all-False when no nan_logits
         # fault is installed): binding it only under faults would give
         # the fault runs a different compiled executable than production
@@ -872,42 +936,45 @@ class Engine:
         # program — enough to flip an argmax near-tie on rows the fault
         # never touched.  One operand, one executable, bit-identical
         # tokens with the harness on or off (DESIGN.md §17).
-        if self._logit_fault is not None:
-            poison = np.array(
-                [r is not None and self._logit_fault.poisons(r.uid)
-                 for r in (self.active[i] for i in range(self.slots))],
-                bool)
-        else:
-            poison = np.zeros(self.slots, bool)
-        self.caches, nxt, ok = self._decode(
-            self.params, self.weight_plans, jnp.asarray(self.last_tok),
-            jnp.asarray(self.pos, jnp.int32), self.caches,
-            jnp.asarray(poison))
+        with TraceAnnotation("engine.decode"):
+            if self._logit_fault is not None:
+                poison = np.array(
+                    [r is not None and self._logit_fault.poisons(r.uid)
+                     for r in (self.active[i] for i in range(self.slots))],
+                    bool)
+            else:
+                poison = np.zeros(self.slots, bool)
+            self.caches, nxt, ok = self._decode(
+                self.params, self.weight_plans, jnp.asarray(self.last_tok),
+                jnp.asarray(self.pos, jnp.int32), self.caches,
+                jnp.asarray(poison))
         self.decode_calls += 1
-        nxt = np.asarray(nxt)
-        ok = np.asarray(ok)
-        for i, req in self.active.items():
-            if req is None:
-                continue
-            if not bool(ok[i]):
-                # poisoned decode: retire this row terminally; sibling
-                # rows in the same batch keep their (finite) tokens
-                finished.append(
-                    self._error_retire(req, "nonfinite_logits", i))
-                continue
-            self.pos[i] += 1
-            tok = int(nxt[i])
-            self._append_token(req, tok)
-            self.last_tok[i] = tok
-            if (len(req.output) >= req.max_new_tokens
-                    or tok == self.eos_id
-                    or self.pos[i] >= self.capacity - 1):
-                req.done = True
-                req.status = "done"
-                finished.append(req)
-                self._retire(i)
-            elif self._deadline_blown(req):
-                finished.append(self._error_retire(req, "deadline", i))
+        with TraceAnnotation("engine.sync"):
+            nxt = np.asarray(nxt)
+            ok = np.asarray(ok)
+        with TraceAnnotation("engine.retire"):
+            for i, req in self.active.items():
+                if req is None:
+                    continue
+                if not bool(ok[i]):
+                    # poisoned decode: retire this row terminally;
+                    # sibling rows keep their (finite) tokens
+                    finished.append(
+                        self._error_retire(req, "nonfinite_logits", i))
+                    continue
+                self.pos[i] += 1
+                tok = int(nxt[i])
+                self._append_token(req, tok)
+                self.last_tok[i] = tok
+                if (len(req.output) >= req.max_new_tokens
+                        or tok == self.eos_id
+                        or self.pos[i] >= self.capacity - 1):
+                    req.done = True
+                    req.status = "done"
+                    finished.append(req)
+                    self._retire(i)
+                elif self._deadline_blown(req):
+                    finished.append(self._error_retire(req, "deadline", i))
         self._maybe_validate()
         return finished
 

@@ -37,6 +37,12 @@ of real elided work.
 
 All modes compute exactly ``x @ w`` — sparsity changes the schedule, not
 the math.
+
+Named scopes: the schedule build (activities, plans, counts) runs under
+``plan`` and the compute arm under ``kernel`` or ``xla``, inside the
+caller's site scope (:mod:`repro.sparse.site`), so each device op's
+``op_name`` reads ``…/<site>/plan/…``.  Scopes are metadata only: the
+compiled program is the same with or without them.
 """
 from __future__ import annotations
 
@@ -354,7 +360,8 @@ def matmul(
                 "sparse.matmul: condense='k' has no effect in dense mode "
                 "— there is no schedule to condense; executing the XLA "
                 "matmul (executed == dense steps)")
-        y = _xla_matmul()
+        with jax.named_scope("xla"):
+            y = _xla_matmul()
         if want_stats:
             dense = jnp.asarray(mt * nt * s)
             steps = stats.StepCounts(dense=dense, sparse=dense,
@@ -364,40 +371,44 @@ def matmul(
         # the stats accounting (under jit XLA would DCE a dead plan, but
         # eager callers would pay the pack for nothing)
         if use_kernel or want_stats:
-            if condense == "k":
-                # element granularity: the fused kernel gathers packed
-                # k's, so both the schedule and the accounting are
-                # ceil(nnz_AND / slice_k) per block (DESIGN.md §12)
-                col_e = _lhs_element(x, x2, block_m, mode)
-                row_e = _rhs_element(w, w_arr, block_n)
-                if use_kernel:
-                    kplan = pln.plan_kcondensed(col_e, row_e, slice_k)
-                    counts = kplan.counts
-                else:  # stats only: skip the schedules' pack
-                    counts = pln.kcondensed_counts(col_e, row_e, slice_k)
-            else:
-                col = _lhs_activity(x, x2, block_m, slice_k, mode)
-                row = _rhs_activity(w, block_n, slice_k)
-                if use_kernel:
-                    ks, counts = pln.plan_from_activity(col, row)
-                else:  # stats only: skip the schedule's pack
-                    counts = pln.counts_from_activity(col, row)
-            if want_stats:
-                steps = pln.counts_to_steps(counts, s)
+            with jax.named_scope("plan"):
+                if condense == "k":
+                    # element granularity: the fused kernel gathers
+                    # packed k's, so both the schedule and the accounting
+                    # are ceil(nnz_AND / slice_k) per block (DESIGN.md §12)
+                    col_e = _lhs_element(x, x2, block_m, mode)
+                    row_e = _rhs_element(w, w_arr, block_n)
+                    if use_kernel:
+                        kplan = pln.plan_kcondensed(col_e, row_e, slice_k)
+                        counts = kplan.counts
+                    else:  # stats only: skip the schedules' pack
+                        counts = pln.kcondensed_counts(col_e, row_e,
+                                                       slice_k)
+                else:
+                    col = _lhs_activity(x, x2, block_m, slice_k, mode)
+                    row = _rhs_activity(w, block_n, slice_k)
+                    if use_kernel:
+                        ks, counts = pln.plan_from_activity(col, row)
+                    else:  # stats only: skip the schedule's pack
+                        counts = pln.counts_from_activity(col, row)
+                if want_stats:
+                    steps = pln.counts_to_steps(counts, s)
         if use_kernel:
             from repro.kernels import bitmap_spgemm as bsk
-            if condense == "k":
-                y = bsk.bitmap_spgemm_kfused_planned(
-                    x2, w_arr, kplan.gk, kplan.counts, block_m=block_m,
-                    block_n=block_n, slice_k=slice_k, interpret=interp,
-                    out_dtype=out_dtype)
-            else:
-                y = bsk.bitmap_spgemm_planned(
-                    x2, w_arr, ks, counts, block_m=block_m,
-                    block_n=block_n, slice_k=slice_k, interpret=interp,
-                    out_dtype=out_dtype)
+            with jax.named_scope("kernel"):
+                if condense == "k":
+                    y = bsk.bitmap_spgemm_kfused_planned(
+                        x2, w_arr, kplan.gk, kplan.counts, block_m=block_m,
+                        block_n=block_n, slice_k=slice_k, interpret=interp,
+                        out_dtype=out_dtype)
+                else:
+                    y = bsk.bitmap_spgemm_planned(
+                        x2, w_arr, ks, counts, block_m=block_m,
+                        block_n=block_n, slice_k=slice_k, interpret=interp,
+                        out_dtype=out_dtype)
         else:
-            y = _xla_matmul()
+            with jax.named_scope("xla"):
+                y = _xla_matmul()
     if steps is not None:
         # kernel path executes the condensed schedule; XLA computes dense
         tape.record(name, steps,
@@ -545,7 +556,8 @@ def grouped_matmul(
             "mode — there is no schedule to condense; executing the XLA "
             "einsum (executed == dense steps)")
     if mode == "dense":
-        y = _xla_grouped()
+        with jax.named_scope("xla"):
+            y = _xla_grouped()
         if want_stats:
             dense = jnp.asarray(
                 e * pln._cdiv(c, block_m) * pln._cdiv(n, block_n) * s)
@@ -554,40 +566,45 @@ def grouped_matmul(
             tape.record(name, steps)
     else:
         if run_kernel or want_stats:
-            if condense == "k":
-                cols_e = _grouped_lhs_element(x, xv, block_m, mode)
-                rows_e = _grouped_rhs_element(w, w_arr, block_n)
-                if run_kernel:
-                    kplan = pln.plan_grouped_kcondensed(cols_e, rows_e,
-                                                        slice_k)
-                    counts = kplan.counts
-                else:  # stats only: skip the schedules' pack
-                    counts = pln.grouped_kcondensed_counts(cols_e, rows_e,
-                                                           slice_k)
-            else:
-                cols = _grouped_lhs_activity(x, xv, block_m, slice_k,
-                                             mode)
-                rows = _grouped_rhs_activity(w, w_arr, block_n, slice_k)
-                if run_kernel:
-                    ks, counts = pln.plan_grouped_activity(cols, rows)
-                else:  # stats only: skip the schedule's pack
-                    counts = pln.grouped_counts_from_activity(cols, rows)
-            if want_stats:
-                steps = pln.grouped_counts_to_steps(counts, s)
+            with jax.named_scope("plan"):
+                if condense == "k":
+                    cols_e = _grouped_lhs_element(x, xv, block_m, mode)
+                    rows_e = _grouped_rhs_element(w, w_arr, block_n)
+                    if run_kernel:
+                        kplan = pln.plan_grouped_kcondensed(
+                            cols_e, rows_e, slice_k)
+                        counts = kplan.counts
+                    else:  # stats only: skip the schedules' pack
+                        counts = pln.grouped_kcondensed_counts(
+                            cols_e, rows_e, slice_k)
+                else:
+                    cols = _grouped_lhs_activity(x, xv, block_m, slice_k,
+                                                 mode)
+                    rows = _grouped_rhs_activity(w, w_arr, block_n,
+                                                 slice_k)
+                    if run_kernel:
+                        ks, counts = pln.plan_grouped_activity(cols, rows)
+                    else:  # stats only: skip the schedule's pack
+                        counts = pln.grouped_counts_from_activity(cols,
+                                                                  rows)
+                if want_stats:
+                    steps = pln.grouped_counts_to_steps(counts, s)
         if run_kernel:
             from repro.kernels import grouped_spgemm as gsk
-            if condense == "k":
-                y = gsk.grouped_spgemm_kfused_planned(
-                    xv, w_arr, kplan.gk, kplan.counts, block_m=block_m,
-                    block_n=block_n, slice_k=slice_k, interpret=interp,
-                    out_dtype=out_dtype)
-            else:
-                y = gsk.grouped_spgemm_planned(
-                    xv, w_arr, ks, counts, block_m=block_m,
-                    block_n=block_n, slice_k=slice_k, interpret=interp,
-                    out_dtype=out_dtype)
+            with jax.named_scope("kernel"):
+                if condense == "k":
+                    y = gsk.grouped_spgemm_kfused_planned(
+                        xv, w_arr, kplan.gk, kplan.counts, block_m=block_m,
+                        block_n=block_n, slice_k=slice_k, interpret=interp,
+                        out_dtype=out_dtype)
+                else:
+                    y = gsk.grouped_spgemm_planned(
+                        xv, w_arr, ks, counts, block_m=block_m,
+                        block_n=block_n, slice_k=slice_k, interpret=interp,
+                        out_dtype=out_dtype)
         else:
-            y = _xla_grouped()
+            with jax.named_scope("xla"):
+                y = _xla_grouped()
         if steps is not None:
             tape.record(name, steps,
                         steps.sparse if run_kernel else None)

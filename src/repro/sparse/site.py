@@ -42,7 +42,10 @@ plumbing with a declarative descriptor:
   record), resolve the site, and forward to
   :mod:`repro.sparse.dispatch` / :mod:`repro.sparse.conv` with
   ``autotune=False`` — the consultation already happened here, exactly
-  once.
+  once.  Each entry point opens ``jax.named_scope(site.name)`` around
+  the call, so every device op of a site carries the site's name in its
+  ``op_name`` (with the dispatch's ``plan`` / ``kernel`` / ``xla``
+  sub-scopes below it).
 
 The attention decode sites are the point of the exercise: ``attn.score``
 is keyed on (M=T slots, N=G heads-per-group, K=head_dim) so the tuned
@@ -276,9 +279,10 @@ def matmul(x, w, site: Optional[OpSite], cfg, *,
     kw = resolved if resolved is not None else resolve(
         st, cfg, m=m, n=_weight_array(w).shape[-1], k=xv.shape[-1],
         dtype=xv.dtype, interpret=interpret)
-    return _guarded(st, _degrade(st, kw), lambda kw2: dsp.matmul(
-        x, w, name=st.name, op=st.op, interpret=interpret,
-        collect_stats=collect_stats, **kw2))
+    with jax.named_scope(st.name):
+        return _guarded(st, _degrade(st, kw), lambda kw2: dsp.matmul(
+            x, w, name=st.name, op=st.op, interpret=interpret,
+            collect_stats=collect_stats, **kw2))
 
 
 def grouped_matmul(x, w, site: Optional[OpSite], cfg, *,
@@ -292,9 +296,11 @@ def grouped_matmul(x, w, site: Optional[OpSite], cfg, *,
     kw = resolved if resolved is not None else resolve(
         st, cfg, m=c, n=_weight_array(w).shape[-1], k=k, e=e,
         dtype=xv.dtype, interpret=interpret)
-    return _guarded(st, _degrade(st, kw), lambda kw2: dsp.grouped_matmul(
-        x, w, name=st.name, interpret=interpret,
-        collect_stats=collect_stats, **kw2))
+    with jax.named_scope(st.name):
+        return _guarded(st, _degrade(st, kw),
+                        lambda kw2: dsp.grouped_matmul(
+                            x, w, name=st.name, interpret=interpret,
+                            collect_stats=collect_stats, **kw2))
 
 
 def project(x, w, site: Optional[OpSite], cfg, *, n_contract: int = 1,
@@ -318,10 +324,11 @@ def project(x, w, site: Optional[OpSite], cfg, *, n_contract: int = 1,
         m *= d
     kw = resolve(st, cfg, m=m, n=n, k=kflat, dtype=xv.dtype,
                  interpret=interpret)
-    return _guarded(st, kw, lambda kw2: dsp.project(
-        x, w, n_contract=n_contract, plan_act=plan_act, name=st.name,
-        op=st.op, interpret=interpret, collect_stats=collect_stats,
-        **kw2))
+    with jax.named_scope(st.name):
+        return _guarded(st, kw, lambda kw2: dsp.project(
+            x, w, n_contract=n_contract, plan_act=plan_act, name=st.name,
+            op=st.op, interpret=interpret, collect_stats=collect_stats,
+            **kw2))
 
 
 def conv2d(x, w, stride: int = 1, *, site: Optional[OpSite] = None,
@@ -341,6 +348,7 @@ def conv2d(x, w, stride: int = 1, *, site: Optional[OpSite] = None,
     m = nb * i2c.out_size(h, kh, stride) * i2c.out_size(wid, kw_sp, stride)
     kw = resolve(st, cfg, m=m, n=f, k=kh * kw_sp * c, dtype=x.dtype,
                  interpret=interpret)
-    return _guarded(st, kw, lambda kw2: scv.conv2d(
-        x, w, stride, name=st.name, interpret=interpret,
-        collect_stats=collect_stats, **kw2))
+    with jax.named_scope(st.name):
+        return _guarded(st, kw, lambda kw2: scv.conv2d(
+            x, w, stride, name=st.name, interpret=interpret,
+            collect_stats=collect_stats, **kw2))
